@@ -53,7 +53,7 @@ def run(n_docs: int = 3, doc_len: int = 24, n_edits: int = 6,
         warmup: bool = True) -> list[dict]:
     import jax
 
-    from repro.common.compile_cache import enable_persistent_compilation_cache
+    from repro.common.compile_cache import enable_compilation_cache
     from repro.configs.vq_opt_125m import smoke_config
     from repro.data.edit_stream import TrafficGenerator
     from repro.models import transformer as T
@@ -61,7 +61,7 @@ def run(n_docs: int = 3, doc_len: int = 24, n_edits: int = 6,
     from repro.serving.batch_server import BatchServer
     from repro.serving.latency import LatencyStats
 
-    enable_persistent_compilation_cache()  # no-op unless the env var is set
+    enable_compilation_cache()
     cfg = smoke_config(vqt=True)
     params = jax.device_get(T.init_params(jax.random.PRNGKey(seed), cfg))
     srv = BatchServer(params, cfg, edit_capacity=4, row_capacity=32,

@@ -220,9 +220,9 @@ def run_fleet(n_replicas: int = 2, n_docs: int = 3, n_sessions: int = 5,
 
 
 def run(full: bool = False, seed: int = 0) -> list[dict]:
-    from repro.common.compile_cache import enable_persistent_compilation_cache
+    from repro.common.compile_cache import enable_compilation_cache
 
-    enable_persistent_compilation_cache()  # no-op unless the env var is set
+    enable_compilation_cache()
     sizes = (1, 2, 4) if full else (2,)
     records = [run_fleet(n_replicas=n, seed=seed) for n in sizes]
     out = os.path.join(ensure_results(), "BENCH_fleet_load.json")

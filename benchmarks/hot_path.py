@@ -68,22 +68,24 @@ def _step_census(eng, B: int, n_cap: int, C: int, R: int,
     bucket = jnp.full((B, C), -1, jnp.int32)
     z = jnp.zeros((B, C), jnp.int32)
     if eng.n_shards > 1:  # the sharded dispatch path (shard_map over mesh)
-        lowered = eng._sharded("apply_edits").lower(state, bucket, z, z, z)
+        lowered = eng._sharded("apply_edits").lower(eng.wts, state, bucket,
+                                                    z, z, z)
     else:
-        lowered = type(eng)._batch_apply_edits_local.lower(
-            eng, state, bucket, z, z, z)
+        lowered = eng._batch_apply_edits_local.jitted.lower(
+            eng, eng.wts, state, bucket, z, z, z)
     compiled = lowered.compile()
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, list) else (ca or {})
     xla_flops = float(ca.get("flops", 0.0))
     xla_bytes = float(ca.get("bytes accessed", 0.0))
     st = launch_stats(compiled.as_text())
     # cost_analysis() prices the per-device program: under shard_map each
     # device runs B / n_shards document rows, so the analytic side must
-    # price the same per-device slice for the fraction to be meaningful
+    # price the same per-device slice for the fraction to be meaningful.
+    # The peaks are the v5e's, the chip the step is built for, whatever
+    # device runs the census.
     rl = edit_step_roofline(
         eng.L, eng.meta, n_cap, C, R, batch=B // eng.n_shards, d_ff=d_ff,
-        xla_flops=xla_flops, xla_bytes=xla_bytes)
+        xla_flops=xla_flops, xla_bytes=xla_bytes, device_kind="TPU v5 lite")
     return {**st.summary(), **rl.summary()}
 
 

@@ -81,14 +81,14 @@ def run(doc_len: int = 96, n_edits: int = 24, n_new: int = 8,
         warmup: bool = True) -> list[dict]:
     import jax
 
-    from repro.common.compile_cache import enable_persistent_compilation_cache
+    from repro.common.compile_cache import enable_compilation_cache
     from repro.configs.vq_opt_125m import smoke_config
     from repro.models import transformer as T
     from repro.serving.batch_server import BatchServer
     from repro.serving.jit_engine import JitIncrementalEngine
     from repro.serving.suggest import SuggestionEngine, oracle_suggestion
 
-    enable_persistent_compilation_cache()  # no-op unless the env var is set
+    enable_compilation_cache()
     cfg = smoke_config(vqt=True)
     params = jax.device_get(T.init_params(jax.random.PRNGKey(seed), cfg))
     srv = BatchServer(params, cfg, edit_capacity=4, row_capacity=32,

@@ -1,54 +1,44 @@
-"""Persistent JAX compilation cache (ROADMAP item 2 slice, ISSUE 6).
+"""JAX's persistent compilation cache for the serving entry points.
 
-The serving stack compiles one step per ``(B, n_cap, C, R)`` bucket shape;
-those traces are deterministic functions of the config, so recompiling them
-on every process restart is pure waste — on the CPU smoke config a single
-bucket step costs ~2s of XLA time, which is exactly the "orchestration
-overhead eats the saved FLOPs" failure mode of BENCH_suggest_reuse.
+The serving stack compiles one step per ``(B, n_cap, C, R)`` bucket shape,
+and at full width each is a whole multi-layer program. Those compiles are
+deterministic functions of the config, so the entry points (``chip_smoke.py``,
+``python -m repro.launch.serve``, the fleet worker, the load benchmarks)
+keep them on disk across process restarts.
 
-``enable_persistent_compilation_cache`` turns on jax's on-disk compilation
-cache so bucket steps survive restarts. It is opt-in (a flag on
-``BatchServer`` / the benchmarks, or the ``REPRO_COMPILE_CACHE_DIR``
-environment variable) because the cache directory is a side effect test
-suites should not create implicitly. CI persists the directory across runs
-via an actions cache keyed on the jax version (see .github/workflows/ci.yml,
-bench-gate job).
+Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+module sets no directory, and the environment's size bound
+(``JAX_COMPILATION_CACHE_MAX_SIZE``) holds. Otherwise the cache lives at one
+fixed path inside the checkout, ``<repo>/.jax_cache`` (git-ignored): the
+directory is part of what lets a later process find an entry, so it must
+not move between runs. That directory is unbounded: one full-width run
+compiles over a hundred programs in a fixed order, and an LRU bound below
+their total size evicts each entry before the next run asks for it.
+Library code (``BatchServer`` and below) never turns the cache on.
 """
 from __future__ import annotations
 
 import os
-from typing import Optional
+from pathlib import Path
 
-ENV_VAR = "REPRO_COMPILE_CACHE_DIR"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = str(Path(__file__).resolve().parents[3] / ".jax_cache")
 
-_enabled_dir: Optional[str] = None
 
-
-def enable_persistent_compilation_cache(
-        cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (or
-    ``$REPRO_COMPILE_CACHE_DIR`` when None). Returns the directory in use,
-    or None when neither source names one — callers treat that as "feature
-    off" rather than an error, so the flag can be threaded unconditionally.
-
-    Idempotent: repeat calls with the same directory are no-ops; a second
-    call with a DIFFERENT directory re-points the cache (jax reads the
-    config value per compilation, so this is safe, just unusual).
-    """
-    global _enabled_dir
-    cache_dir = cache_dir or os.environ.get(ENV_VAR) or None
-    if cache_dir is None:
-        return None
-    cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
-    if _enabled_dir == cache_dir:
-        return cache_dir
+def enable_compilation_cache() -> str:
+    """Turn the persistent compilation cache on for this process and return
+    its directory: ``$JAX_COMPILATION_CACHE_DIR`` when set, else
+    ``DEFAULT_DIR``. Every compile is cached — serving bucket steps are
+    small but hot, so JAX's default one-second floor would skip many.
+    Idempotent."""
     import jax
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # serving bucket steps are small but hot — cache everything, not just
-    # the >1s compiles jax defaults to
+    cache_dir = os.environ.get(ENV_VAR)
+    if not cache_dir:
+        cache_dir = DEFAULT_DIR
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        jax.config.update("jax_compilation_cache_max_size", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _enabled_dir = cache_dir
     return cache_dir

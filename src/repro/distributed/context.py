@@ -88,21 +88,6 @@ def use_mesh(mesh: Mesh):
         set_ctx(prev)
 
 
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: newer releases expose it as
-    ``jax.shard_map`` (with ``check_vma``), older ones as
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``). Both
-    checks are disabled — serving and MoE shards close over replicated
-    weight stacks, which the replication checker cannot always prove."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def constrain(x: jax.Array, *logical_axes) -> jax.Array:
     """Apply a sharding constraint using logical axis names (no-op w/o ctx)."""
     ctx = get_ctx()

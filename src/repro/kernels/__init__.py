@@ -7,3 +7,11 @@
 #                      on TPU (DESIGN.md §3).
 # Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 # wrapper), ref.py (pure-jnp oracle used by the test sweeps).
+import jax
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: everywhere except a TPU
+    backend, where they always compile. There is no fallback — a backend
+    that fails to start raises here instead of silently interpreting."""
+    return jax.default_backend() != "tpu"

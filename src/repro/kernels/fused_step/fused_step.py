@@ -47,34 +47,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.common.precision import SERVING_PRECISION
+
 
 def _kernel(q_ref, kn_ref, ko_ref, vcn_ref, vco_ref, mask_ref, tb_ref,
             counts_ref, bias_ref, t_ref, codes_ref, *, scale: float, g: int):
-    # q_ref: [BR, g, dh]; kn/ko: [g, C, dh]; vcn/vco: [g, C, Q];
-    # mask: [BR, C]; tb: [BR, g, Q]; counts: [BR, 1]; bias: [1, Q];
-    # t: [BR, g, Q]; codes: [BR, 1]
+    # Head-major blocks, so each head is a leading-axis index and the
+    # trailing (sublane, lane) pair of every block is a full or 8-aligned
+    # extent — the TPU tiling rule:
+    # q_ref: [g, BR, dh]; kn/ko: [g, C, dh]; vcn/vco: [g, C, Q];
+    # mask: [BR, C]; tb/t: [g, BR, Q]; counts: [BR, 1]; bias: [1, 1, Q];
+    # codes: [1, BR, 1]
     mask = mask_ref[...].astype(jnp.float32)  # [BR, C]
     acc = None
     for j in range(g):
-        q = q_ref[:, j, :]  # [BR, dh]
+        q = q_ref[j]  # [BR, dh]
 
         def contrib(k_ref, vc_ref, sign):
             s = jax.lax.dot_general(
                 q, k_ref[j], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                precision=SERVING_PRECISION, preferred_element_type=jnp.float32,
             ) * scale  # [BR, C]
             w = jax.nn.gelu(s, approximate=True) * mask
             return sign * jax.lax.dot_general(
                 w, vc_ref[j].astype(jnp.float32), (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
+                precision=SERVING_PRECISION, preferred_element_type=jnp.float32,
             )  # [BR, Q]
 
-        Tj = (tb_ref[:, j, :].astype(jnp.float32)
+        Tj = (tb_ref[j].astype(jnp.float32)
               + contrib(kn_ref, vcn_ref, 1.0) + contrib(ko_ref, vco_ref, -1.0))
-        t_ref[:, j, :] = Tj.astype(t_ref.dtype)
+        t_ref[j] = Tj.astype(t_ref.dtype)
         acc = Tj if acc is None else acc + Tj
-    scores = acc / counts_ref[...] + bias_ref[0][None, :]  # [BR, Q]
-    codes_ref[:, 0] = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+    scores = acc / counts_ref[...] + bias_ref[0]  # [BR, Q]
+    # first-max argmax from 2-D reductions (jnp.argmax's tie rule) — keeps
+    # the result [BR, 1] without a 1-D relayout
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    codes_ref[0] = jnp.min(jnp.where(scores == top, lane, scores.shape[-1]),
+                           axis=-1, keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -94,48 +104,55 @@ def fused_step_kernel(
     block_r: int = 128,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (T_all [n, H, Q] f32, codes [n, hq] int32)."""
+    """Returns (T_all [n, H, Q] f32, codes [n, hq] int32).
+
+    The kernel runs head-major (q and T transposed to [H, n, *]) over a
+    (row-block, vq-head) grid; ``block_r`` must be a multiple of 8 for the
+    compiled TPU kernel. Under ``jax.vmap`` the pallas batching rule adds
+    a leading document axis to the grid (the engine's batched route)."""
     n, H, dh = q.shape
     C = k_new.shape[1]
     Q = vc_new.shape[-1]
     g = heads_per_vq
     hq = H // g
     scale = dh ** -0.5
+    q_t = jnp.swapaxes(q, 0, 1)  # [H, n, dh]
+    tb_t = jnp.swapaxes(T_base, 0, 1)  # [H, n, Q]
     counts = counts.astype(jnp.float32).reshape(n, 1)
     pad = (-n) % block_r
     if pad:
-        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        q_t = jnp.pad(q_t, ((0, 0), (0, pad), (0, 0)))
         mask = jnp.pad(mask, ((0, pad), (0, 0)))
-        T_base = jnp.pad(T_base, ((0, pad), (0, 0), (0, 0)))
+        tb_t = jnp.pad(tb_t, ((0, 0), (0, pad), (0, 0)))
         # pad counts with 1 so the padded rows' score divide stays finite
         counts = jnp.pad(counts, ((0, pad), (0, 0)), constant_values=1.0)
     Np = n + pad
-    grid = (Np // block_r, hq)
-    T_all, codes = pl.pallas_call(
+    T_t, codes = pl.pallas_call(
         functools.partial(_kernel, scale=scale, g=g),
-        grid=grid,
+        grid=(Np // block_r, hq),
         in_specs=[
-            pl.BlockSpec((block_r, g, dh), lambda i, h: (i, h, 0)),
+            pl.BlockSpec((g, block_r, dh), lambda i, h: (h, i, 0)),
             pl.BlockSpec((g, C, dh), lambda i, h: (h, 0, 0)),
             pl.BlockSpec((g, C, dh), lambda i, h: (h, 0, 0)),
             pl.BlockSpec((g, C, Q), lambda i, h: (h, 0, 0)),
             pl.BlockSpec((g, C, Q), lambda i, h: (h, 0, 0)),
             pl.BlockSpec((block_r, C), lambda i, h: (i, 0)),
-            pl.BlockSpec((block_r, g, Q), lambda i, h: (i, h, 0)),
+            pl.BlockSpec((g, block_r, Q), lambda i, h: (h, i, 0)),
             pl.BlockSpec((block_r, 1), lambda i, h: (i, 0)),
-            pl.BlockSpec((1, Q), lambda i, h: (h, 0)),
+            pl.BlockSpec((1, 1, Q), lambda i, h: (h, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((block_r, g, Q), lambda i, h: (i, h, 0)),
-            pl.BlockSpec((block_r, 1), lambda i, h: (i, h)),
+            pl.BlockSpec((g, block_r, Q), lambda i, h: (h, i, 0)),
+            pl.BlockSpec((1, block_r, 1), lambda i, h: (h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Np, H, Q), jnp.float32),
-            jax.ShapeDtypeStruct((Np, hq), jnp.int32),
+            jax.ShapeDtypeStruct((H, Np, Q), jnp.float32),
+            jax.ShapeDtypeStruct((hq, Np, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(q, k_new, k_old, vc_new, vc_old, mask, T_base, counts, vq_bias)
-    return T_all[:n], codes[:n]
+    )(q_t, k_new, k_old, vc_new, vc_old, mask, tb_t, counts,
+      vq_bias.reshape(hq, 1, Q))
+    return jnp.swapaxes(T_t[:, :n], 0, 1), codes[:, :n, 0].T
 
 
 @functools.partial(jax.jit,
@@ -155,54 +172,15 @@ def fused_step_kernel_batched(
     block_r: int = 128,
     interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
-    """Batched-serving variant: same fused body over a grid with a leading
-    *batch* dimension — one (document, row-block, vq-head) cell per grid
-    point, so B documents' whole edit steps run as one ``pallas_call`` per
-    layer. The vq_bias block is batch-invariant and stays resident.
+    """Batched-serving variant: the pallas batching rule turns the
+    per-document grid into a (document, row-block, vq-head) grid, so B
+    documents' whole edit steps run as one ``pallas_call`` per layer. The
+    vq_bias block is batch-invariant.
     Returns (T_all [B, n, H, Q] f32, codes [B, n, hq] int32)."""
-    B, n, H, dh = q.shape
-    C = k_new.shape[2]
-    Q = vc_new.shape[-1]
-    g = heads_per_vq
-    hq = H // g
-    scale = dh ** -0.5
-    counts = counts.astype(jnp.float32).reshape(B, n, 1)
-    pad = (-n) % block_r
-    if pad:
-        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        mask = jnp.pad(mask, ((0, 0), (0, pad), (0, 0)))
-        T_base = jnp.pad(T_base, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        counts = jnp.pad(counts, ((0, 0), (0, pad), (0, 0)),
-                         constant_values=1.0)
-    Np = n + pad
-    grid = (B, Np // block_r, hq)
-    T_all, codes = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, g=g),
-        grid=grid,
-        in_specs=[
-            # None squeezes the batch dim so the unbatched kernel body is
-            # reused verbatim — the batch lives purely in the grid.
-            pl.BlockSpec((None, block_r, g, dh), lambda b, i, h: (b, i, h, 0)),
-            pl.BlockSpec((None, g, C, dh), lambda b, i, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, g, C, dh), lambda b, i, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, g, C, Q), lambda b, i, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, g, C, Q), lambda b, i, h: (b, h, 0, 0)),
-            pl.BlockSpec((None, block_r, C), lambda b, i, h: (b, i, 0)),
-            pl.BlockSpec((None, block_r, g, Q), lambda b, i, h: (b, i, h, 0)),
-            pl.BlockSpec((None, block_r, 1), lambda b, i, h: (b, i, 0)),
-            pl.BlockSpec((1, Q), lambda b, i, h: (h, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_r, g, Q), lambda b, i, h: (b, i, h, 0)),
-            pl.BlockSpec((None, block_r, 1), lambda b, i, h: (b, i, h)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Np, H, Q), jnp.float32),
-            jax.ShapeDtypeStruct((B, Np, hq), jnp.int32),
-        ],
-        interpret=interpret,
-    )(q, k_new, k_old, vc_new, vc_old, mask, T_base, counts, vq_bias)
-    return T_all[:, :n], codes[:, :n]
+    one = functools.partial(fused_step_kernel, heads_per_vq=heads_per_vq,
+                            block_r=block_r, interpret=interpret)
+    return jax.vmap(one, in_axes=(0,) * 8 + (None,))(
+        q, k_new, k_old, vc_new, vc_old, mask, T_base, counts, vq_bias)
 
 
 def _gate_kernel(xn_ref, xo_ref, keep_ref, *, threshold: float):

@@ -4,22 +4,16 @@ The engine hands this the fully-folded per-(row, column) mask (changed
 columns & causal order & row validity & dirty-row exclusion), the score
 buffer with the dirty rows' full recompute already scattered in, and the
 per-row attended-column counts; the kernel does the rest in one launch per
-layer. Falls back to interpret mode off-TPU (bit-identical math, Python
-execution of the kernel body) so the whole stack runs on CPU.
+layer. Off a TPU backend the kernel runs in interpret mode (the same
+math, the kernel body executed by XLA) so the whole stack runs on CPU; on a
+TPU it always compiles (``repro.kernels.interpret_mode``).
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.fused_step.fused_step import fused_step_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def fused_patch_assign(q, k_new, k_old, vc_new, vc_old, mask, T_base, counts,
@@ -37,7 +31,7 @@ def fused_patch_assign(q, k_new, k_old, vc_new, vc_old, mask, T_base, counts,
     return fused_step_kernel(
         q, k_new, k_old, vc_new, vc_old, mask.astype(jnp.float32), T_base,
         counts, vq_bias, heads_per_vq=heads_per_vq, block_r=block_r,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 
@@ -57,7 +51,7 @@ def fused_patch_assign_batched(q, k_new, k_old, vc_new, vc_old, mask, T_base,
     return fused_step_kernel_batched(
         q, k_new, k_old, vc_new, vc_old, mask.astype(jnp.float32), T_base,
         counts, vq_bias, heads_per_vq=heads_per_vq, block_r=block_r,
-        interpret=not _on_tpu(),
+        interpret=interpret_mode(),
     )
 
 
@@ -75,4 +69,4 @@ def delta_gate(x_new, x_old, threshold: float, *, block_r: int = 128):
     from repro.kernels.fused_step.fused_step import delta_gate_kernel
 
     return delta_gate_kernel(x_new, x_old, threshold=float(threshold),
-                             block_r=block_r, interpret=not _on_tpu())
+                             block_r=block_r, interpret=interpret_mode())

@@ -9,14 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.gated_attention.gated_attention import gated_attention_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def gated_attention(
@@ -36,7 +30,7 @@ def gated_attention(
     fold = lambda a: jnp.moveaxis(a, 2, 1).reshape(b * H, a.shape[1], a.shape[-1])
     out = gated_attention_kernel(
         fold(q), fold(k), fold(v),
-        block_q=block_q, block_k=block_k, interpret=not _on_tpu(),
+        block_q=block_q, block_k=block_k, interpret=interpret_mode(),
     )  # [b*H, nq, dh]
     out = out.reshape(b, H, nq, dh)
     return jnp.moveaxis(out, 1, 2).reshape(b, nq, H * dh)

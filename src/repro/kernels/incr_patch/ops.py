@@ -6,17 +6,10 @@ a distinct static compile — the standard serving-system bucketing pattern.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.incr_patch.incr_patch import incr_patch_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def bucket_capacity(n: int, minimum: int = 8) -> int:
@@ -39,7 +32,7 @@ def incr_patch(q, k_new, k_old, vc_new, vc_old, mask, *, row_valid=None,
         mask = mask * row_valid.astype(jnp.float32)[:, None]
     return incr_patch_kernel(
         q, k_new, k_old, vc_new, vc_old, mask,
-        block_r=block_r, interpret=not _on_tpu(),
+        block_r=block_r, interpret=interpret_mode(),
     )
 
 
@@ -62,5 +55,5 @@ def incr_patch_batched(q, k_new, k_old, vc_new, vc_old, mask, *,
         mask = mask * row_valid.astype(jnp.float32)[:, :, None]
     return incr_patch_kernel_batched(
         q, k_new, k_old, vc_new, vc_old, mask,
-        block_r=block_r, interpret=not _on_tpu(),
+        block_r=block_r, interpret=interpret_mode(),
     )

@@ -1,21 +1,15 @@
 """Jit'd public wrapper: quantize attention outputs with the Pallas kernel.
 
-Falls back to interpret mode off-TPU (bit-identical math, Python execution of
-the kernel body) so the whole stack runs on CPU.
+Runs in interpret mode off a TPU backend (``repro.kernels.interpret_mode``)
+so the whole stack runs on CPU.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.vq_assign.vq_assign import vq_assign_kernel
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
 
 
 def vq_assign(
@@ -30,7 +24,7 @@ def vq_assign(
     assert hq * dv == d, (codebook.shape, d)
     xh = x.reshape(-1, hq, dv)
     idx, xq = vq_assign_kernel(xh, codebook, block_n=block_n,
-                               interpret=not _on_tpu())
+                               interpret=interpret_mode())
     return idx.reshape(*lead, hq), xq.reshape(*lead, d).astype(x.dtype)
 
 
@@ -50,5 +44,5 @@ def vq_assign_batched(
     assert hq * dv == d, (codebook.shape, d)
     xh = x.reshape(B, N, hq, dv)
     idx, xq = vq_assign_kernel_batched(xh, codebook, block_n=block_n,
-                                       interpret=not _on_tpu())
+                                       interpret=interpret_mode())
     return idx, xq.reshape(B, N, d).astype(x.dtype)

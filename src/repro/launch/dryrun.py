@@ -40,16 +40,15 @@ from repro.configs.base import ArchConfig
 from repro.distributed.context import use_mesh
 from repro.launch.hlo_stats import collective_stats
 from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import chip_peaks
 from repro.launch.sharding import batch_shardings, cache_shardings, param_shardings
 from repro.launch.specs import SHAPES, ShapeCfg, decode_token_specs, input_specs, shape_supported
 from repro.models import transformer as T
 from repro.serving.decode import make_serve_step
 from repro.training import make_schedule, make_train_step, train_state_init
 
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
-ICI_BW = 50e9  # bytes/s/link
+# the production mesh is TPU v5e (launch/mesh.py)
+PEAKS = chip_peaks("TPU v5 lite")
 
 
 # --------------------------------------------------------------- step builders
@@ -419,9 +418,10 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, roofline: bool) -> d
             chips = 256
             t = rl["total"]
             terms = {
-                "compute_s": t["flops"] / PEAK_FLOPS,
-                "memory_s": t["bytes"] / HBM_BW,
-                "collective_s": t["collective_bytes"] / ICI_BW,
+                "compute_s": t["flops"] / PEAKS["flops"],
+                "memory_s": t["bytes"] / PEAKS["hbm_bytes_per_s"],
+                "collective_s": (t["collective_bytes"]
+                                 / PEAKS["ici_bytes_per_s_per_link"]),
             }
             terms["bottleneck"] = max(terms, key=lambda k: terms[k])
             mf = model_flops(cfg, shape)

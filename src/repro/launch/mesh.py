@@ -9,16 +9,23 @@ import numpy as np
 from jax.sharding import Mesh
 
 
+def _auto_mesh(shape: tuple, axes: tuple) -> Mesh:
+    # Auto axes: the model code places values with with_sharding_constraint,
+    # which jax.make_mesh's default Explicit axes refuse
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod (TPU v5e); 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke runs."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_serving_mesh(n_devices: Optional[int] = None, *,

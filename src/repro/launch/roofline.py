@@ -19,11 +19,23 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-# Single-chip peaks mirrored from launch/dryrun.py (TPU v5e-class): the
-# bench reports ratios (achieved / roofline), which divide these out on
-# same-chip comparisons, but absolute seconds need SOME peak to anchor to.
-PEAK_FLOPS = 197e12  # bf16
-HBM_BW = 819e9  # bytes/s
+# Per-chip peaks keyed by ``jax.Device.device_kind``, the one table every
+# roofline in the repo reads. Source: Google Cloud documentation, "TPU v5e"
+# (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over 4 links = 50 GB/s per link).
+CHIP_PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "ici_bytes_per_s_per_link": 50e9},
+}
+
+
+def chip_peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``. A device the table does not
+    know is an error, never a default."""
+    if device_kind not in CHIP_PEAKS:
+        raise ValueError(f"no peak figures for device kind {device_kind!r}; "
+                         f"known: {sorted(CHIP_PEAKS)}")
+    return CHIP_PEAKS[device_kind]
 
 
 def edit_step_flops(n_layers: int, meta: dict, n_cap: int, C: int,
@@ -81,8 +93,8 @@ class RooflineReport:
     analytic_bytes: float
     xla_flops: float
     xla_bytes: float
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
+    peak_flops: float
+    hbm_bw: float
 
     @property
     def compute_s(self) -> float:
@@ -121,17 +133,19 @@ class RooflineReport:
 
 def edit_step_roofline(n_layers: int, meta: dict, n_cap: int, C: int, R: int,
                        *, xla_flops: float, xla_bytes: float,
-                       weight_bytes: int = 0, batch: int = 1,
-                       d_ff: int = 0) -> RooflineReport:
+                       device_kind: str, weight_bytes: int = 0,
+                       batch: int = 1, d_ff: int = 0) -> RooflineReport:
     """Price a ``(B, n_cap, C, R)`` batched edit step against its analytic
-    floor. ``xla_flops``/``xla_bytes`` come from the compiled step's
-    ``cost_analysis()`` (whole-batch numbers); the analytic side scales the
-    per-document model by ``batch`` and charges the weights once (they are
-    shared across the batch)."""
+    floor on a ``device_kind`` chip. ``xla_flops``/``xla_bytes`` come from
+    the compiled step's ``cost_analysis()`` (whole-batch numbers); the
+    analytic side scales the per-document model by ``batch`` and charges
+    the weights once (they are shared across the batch)."""
+    peaks = chip_peaks(device_kind)
     return RooflineReport(
         analytic_flops=batch * edit_step_flops(n_layers, meta, n_cap, C, R,
                                                d_ff=d_ff),
         analytic_bytes=(batch * edit_step_bytes(n_layers, meta, n_cap)
                         + float(weight_bytes)),
         xla_flops=float(xla_flops), xla_bytes=float(xla_bytes),
+        peak_flops=peaks["flops"], hbm_bw=peaks["hbm_bytes_per_s"],
     )

@@ -27,6 +27,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compilation_cache
 from repro.configs import get_config
 from repro.core.edits import random_atomic_edit
 from repro.data import SyntheticCorpus
@@ -246,6 +247,7 @@ def main():
                          "the document router (ISSUE 10)")
     args = ap.parse_args()
 
+    enable_compilation_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     assert cfg.vqt is not None, "serve demo requires a VQT config (e.g. vq-opt-125m)"
     if args.fleet:

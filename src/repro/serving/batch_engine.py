@@ -32,9 +32,10 @@ Multi-device serving (DESIGN.md §6)
 -----------------------------------
 Pass ``mesh=`` (see ``repro.launch.mesh.make_serving_mesh``) to shard the
 document axis over a 1-D device mesh: every batched entry point becomes a
-``shard_map`` over per-shard ``[B/n_dev, ...]`` slices (weights replicate
-via closure), so each device runs the ordinary vmapped step — including
-the batched Pallas kernels, whose grids see only the local batch slice —
+``shard_map`` over per-shard ``[B/n_dev, ...]`` slices (the weight
+pytree is an argument, replicated on every device), so each device runs
+the ordinary vmapped step — including the batched Pallas kernels, whose
+grids see only the local batch slice —
 and no cross-device communication exists anywhere in a dispatch (sequence
 order is position-id order *within* each document, so the batch axis is
 embarrassingly parallel). ``B`` must be a multiple of the mesh's batch
@@ -53,11 +54,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.context import shard_map_compat
+from repro.common.precision import pinned_precision
 from repro.launch.sharding import serving_batch_sharding
-from repro.serving.jit_engine import JitIncrementalEngine, JitState, KVExport
+from repro.serving.jit_engine import (
+    JitIncrementalEngine, JitState, KVExport, jit_with_weights,
+)
 
 # A JitState whose every leaf carries a leading [B] document axis.
 BatchedJitState = JitState
@@ -99,6 +102,9 @@ class BatchedJitEngine(JitIncrementalEngine):
                          delta_threshold=delta_threshold, _weights=_weights)
         if mesh is not None:
             serving_batch_sharding(mesh, batch_axis)  # validates the axis
+            # one replicated copy per device; a no-op for weights a sibling
+            # engine on the same mesh already placed
+            self.wts = jax.device_put(self.wts, NamedSharding(mesh, P()))
         self.mesh = mesh
         self.batch_axis = batch_axis
         self._sharded_fns: dict[str, callable] = {}
@@ -118,29 +124,31 @@ class BatchedJitEngine(JitIncrementalEngine):
                 "(BatchServer pads to a multiple automatically)")
 
     def _sharded(self, name: str):
-        """jit(shard_map(vmapped impl)) with every input/output pytree leaf
+        """jit(shard_map(vmapped impl)) taking ``(wts, *batched args)``: the
+        weight pytree replicated (``P()``), every batched input/output leaf
         sharded on the batch axis (a single ``P(batch_axis)`` acts as the
         pytree-prefix spec for states, buckets and exports alike). Built
         lazily per entry point and cached per engine — one compiled step
-        per (B, n_cap, C, R) exactly like the single-device path."""
+        per (B, n_cap, C, R) exactly like the single-device path. The
+        replication checker is off: it cannot always prove the per-shard
+        outputs of a replicated-weight body."""
         fn = self._sharded_fns.get(name)
         if fn is None:
+            batched = lambda impl: (  # noqa: E731
+                lambda w, *a: jax.vmap(functools.partial(impl, w))(*a))
             builders = {
-                "full_forward": (
-                    lambda t, p, v: jax.vmap(self._full_forward_impl)(t, p, v),
-                    3),
-                "apply_edits": (
-                    lambda s, sl, tk, pi, op: jax.vmap(self._apply_edits_impl)(
-                        s, sl, tk, pi, op), 5),
-                "export_kv": (lambda s: jax.vmap(self._export_kv_impl)(s), 1),
-                "logits_at": (
-                    lambda s, i: jax.vmap(self._logits_at_impl)(s, i), 2),
+                "full_forward": (batched(self._full_forward_impl), 3),
+                "apply_edits": (batched(self._apply_edits_impl), 5),
+                "export_kv": (
+                    lambda w, s: jax.vmap(self._export_kv_impl)(s), 1),
+                "logits_at": (batched(self._logits_at_impl), 2),
             }
             body, n_args = builders[name]
             spec = serving_batch_sharding(self.mesh, self.batch_axis).spec
-            fn = jax.jit(shard_map_compat(
-                body, mesh=self.mesh, in_specs=(spec,) * n_args,
-                out_specs=spec))
+            fn = jax.jit(jax.shard_map(
+                pinned_precision(body), mesh=self.mesh,
+                in_specs=(P(),) + (spec,) * n_args,
+                out_specs=spec, check_vma=False))
             self._sharded_fns[name] = fn
         return fn
 
@@ -155,15 +163,16 @@ class BatchedJitEngine(JitIncrementalEngine):
             self._check_batch(tokens.shape[0])
             if valid is None:
                 valid = jnp.ones(tokens.shape, bool)
-            return self._sharded("full_forward")(tokens, positions, valid)
+            return self._sharded("full_forward")(self.wts, tokens, positions,
+                                                 valid)
         return self._batch_full_forward_local(tokens, positions, valid)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def _batch_full_forward_local(self, tokens, positions, valid=None):
+    @jit_with_weights
+    def _batch_full_forward_local(self, wts, tokens, positions, valid=None):
+        ff = functools.partial(self._full_forward_impl, wts)
         if valid is None:
-            return jax.vmap(
-                lambda t, p: self._full_forward_impl(t, p))(tokens, positions)
-        return jax.vmap(self._full_forward_impl)(tokens, positions, valid)
+            return jax.vmap(lambda t, p: ff(t, p))(tokens, positions)
+        return jax.vmap(ff)(tokens, positions, valid)
 
     def batch_apply_edits(
         self, state: BatchedJitState, slot: jax.Array, tok: jax.Array,
@@ -176,12 +185,14 @@ class BatchedJitEngine(JitIncrementalEngine):
         batch server's fallback + capacity-doubling policy)."""
         if self.n_shards > 1:
             self._check_batch(slot.shape[0])
-            return self._sharded("apply_edits")(state, slot, tok, pos_id, op)
+            return self._sharded("apply_edits")(self.wts, state, slot, tok,
+                                                pos_id, op)
         return self._batch_apply_edits_local(state, slot, tok, pos_id, op)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def _batch_apply_edits_local(self, state, slot, tok, pos_id, op):
-        return jax.vmap(self._apply_edits_impl)(state, slot, tok, pos_id, op)
+    @jit_with_weights
+    def _batch_apply_edits_local(self, wts, state, slot, tok, pos_id, op):
+        return jax.vmap(functools.partial(self._apply_edits_impl, wts))(
+            state, slot, tok, pos_id, op)
 
     def batch_apply_replaces(
         self, state: BatchedJitState, edit_pos: jax.Array, edit_tok: jax.Array,
@@ -218,7 +229,7 @@ class BatchedJitEngine(JitIncrementalEngine):
         current scheduler exports per document as it refreshes)."""
         if self.n_shards > 1:
             self._check_batch(state.tokens.shape[0])
-            return self._sharded("export_kv")(state)
+            return self._sharded("export_kv")(self.wts, state)
         return self._batch_export_kv_local(state)
 
     @functools.partial(jax.jit, static_argnums=0)
@@ -231,9 +242,10 @@ class BatchedJitEngine(JitIncrementalEngine):
         valid slot for padded docs — the host scheduler tracks it)."""
         if self.n_shards > 1:
             self._check_batch(index.shape[0])
-            return self._sharded("logits_at")(state, index)
+            return self._sharded("logits_at")(self.wts, state, index)
         return self._batch_logits_at_local(state, index)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def _batch_logits_at_local(self, state, index):
-        return jax.vmap(self._logits_at_impl)(state, index)
+    @jit_with_weights
+    def _batch_logits_at_local(self, wts, state, index):
+        return jax.vmap(functools.partial(self._logits_at_impl, wts))(
+            state, index)

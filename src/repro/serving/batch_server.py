@@ -93,7 +93,6 @@ from repro.checkpoint.store import (
     restore_serving_document, save_serving_document,
 )
 from repro.common.bucketing import capacity_class, next_pow2
-from repro.common.compile_cache import enable_persistent_compilation_cache
 from repro.configs.base import ArchConfig
 from repro.core.edits import Edit
 from repro.core.positional import PositionAllocator
@@ -277,8 +276,7 @@ class BatchServer:
                  batch_axis: str = "data",
                  device_budget_bytes: Optional[int] = None,
                  host_budget_bytes: Optional[int] = None,
-                 spill_dir: Optional[str] = None,
-                 compilation_cache_dir: Optional[str] = None):
+                 spill_dir: Optional[str] = None):
         """The fused ragged hot path (DESIGN.md §9) is ON by default:
         ``use_fused_kernel`` routes each layer's patch + requantize through
         one ``fused_step`` Pallas launch; ``capacity_class_step`` spaces the
@@ -306,11 +304,6 @@ class BatchServer:
             raise ValueError("max_batch must be >= 1")
         if capacity_class_step < 2:
             raise ValueError("capacity_class_step must be >= 2")
-        # persistent compilation cache (opt-in): per-(B, n_cap, C, R) bucket
-        # steps survive process restarts instead of re-tracing + re-compiling
-        # on every boot. None still honors $REPRO_COMPILE_CACHE_DIR.
-        self.compilation_cache_dir = enable_persistent_compilation_cache(
-            compilation_cache_dir)
         self.cfg = cfg
         self.C = next_pow2(edit_capacity)
         self.R = next_pow2(row_capacity)

@@ -58,6 +58,33 @@ from repro.serving.state_store import cold_path_for
 _FRAME_OPS = 64  # max ops coalesced per RPC frame
 _READY_TIMEOUT_S = 600.0  # worker boot = jax import + params init
 _RECOVER_TIMEOUT_S = 600.0  # failover import/reopen may pay a first compile
+_TPU_PORT_BASE = 8476  # libtpu's per-process port; one per replica
+
+
+def accelerator_chips(env: dict) -> int:
+    """Accelerator chips JAX sees on this host, asked of a short-lived child
+    process: the router itself never starts a JAX backend, because one in
+    the parent would hold the chips its workers need. 0 when
+    ``JAX_PLATFORMS`` pins the CPU or JAX's default backend is the CPU."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return 0
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; print(jax.default_backend(), jax.device_count())"],
+        env=env, capture_output=True, text=True, timeout=_READY_TIMEOUT_S,
+        check=True)
+    backend, count = out.stdout.split()[-2:]
+    return 0 if backend == "cpu" else int(count)
+
+
+def worker_env(idx: int) -> dict:
+    """Environment that gives replica ``idx`` chip ``idx`` alone. It is set
+    when the worker process starts, so it holds before the worker imports
+    JAX."""
+    return {"TPU_VISIBLE_CHIPS": str(idx),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(_TPU_PORT_BASE + idx)}
 
 
 class ReplicaDiedError(RuntimeError):
@@ -129,8 +156,7 @@ class FleetRouter:
                  server_kwargs: Optional[dict] = None,
                  max_batch_delay_ms: float = 5.0,
                  bucket_docs: Optional[int] = None,
-                 heartbeat_interval_s: Optional[float] = 2.0,
-                 worker_env: Optional[dict] = None):
+                 heartbeat_interval_s: Optional[float] = 2.0):
         if n_replicas < 1:
             raise ValueError("n_replicas must be >= 1")
         self.arch = arch
@@ -167,13 +193,19 @@ class FleetRouter:
             os.path.dirname(os.path.abspath(__file__)))))
         env["PYTHONPATH"] = src_root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env.update(worker_env or {})
+        # on an accelerator host every replica owns one chip
+        chips = accelerator_chips(env)
+        if chips and n_replicas > chips:
+            raise ValueError(
+                f"{n_replicas} replicas need {n_replicas} chips, but this "
+                f"host has {chips}: each replica serves from a chip of its "
+                "own")
         self.replicas: list[_Replica] = []
         for idx in range(n_replicas):
             proc = subprocess.Popen(
                 [sys.executable, "-m", "repro.serving.fleet.worker"],
-                stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-                stderr=None, env=env)
+                stdin=subprocess.PIPE, stdout=subprocess.PIPE, stderr=None,
+                env={**env, **worker_env(idx)} if chips else env)
             r = _Replica(idx, proc)
             send_msg(proc.stdin, {**spec_common, "replica": r.name})
             self.replicas.append(r)

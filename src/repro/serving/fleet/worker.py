@@ -40,22 +40,18 @@ _TICKET_TIMEOUT_S = 3600.0
 
 class _Worker:
     def __init__(self, spec: dict):
-        # jax lands here (not at module import) so spec-derived env decisions
-        # could still be made by the parent before the heavyweight import
+        # jax lands here, not at module import: the router's per-replica
+        # chip pinning (``router.worker_env``) is in the environment already
         import jax
 
-        from repro.common.compile_cache import (
-            enable_persistent_compilation_cache,
-        )
+        from repro.common.compile_cache import enable_compilation_cache
         from repro.configs import get_config
         from repro.models import transformer
         from repro.serving.async_server import AsyncBatchServer
         from repro.serving.batch_server import BatchServer
 
-        # workers inherit REPRO_COMPILE_CACHE_DIR from the router's env —
-        # on CI every replica replays the same compiled steps (no-op when
-        # the env var is unset)
-        enable_persistent_compilation_cache()
+        # every replica replays the same compiled steps from one cache
+        enable_compilation_cache()
         self.replica = spec["replica"]
         self.cold_dir = spec["cold_dir"]
         cfg = get_config(spec.get("arch", "vq-opt-125m"),

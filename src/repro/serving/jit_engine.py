@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.common.precision import pinned_precision
 
 # Edit opcodes for the generic ``apply_edits`` step (int32 bucket entries).
 OP_REPLACE = 0
@@ -224,6 +225,25 @@ def _weights_from_params(params: dict, cfg: ArchConfig):
     return W, extras, meta
 
 
+def jit_with_weights(fn):
+    """``jax.jit`` an engine method ``fn(self, wts, *args)`` with the engine
+    static and its device weights passed as a jit ARGUMENT, never closed
+    over: a closed-over array lowers to an HLO constant, so every compiled
+    shape would carry its own copy of every weight. Its matmuls trace at
+    the serving precision (``repro.common.precision``). The returned method
+    takes ``(self, *args)`` and supplies ``self.wts``; the jitted function
+    itself stays reachable as ``.jitted`` (``.jitted.lower(eng, wts, ...)``
+    compiles a step from shapes alone)."""
+    jitted = jax.jit(pinned_precision(fn), static_argnums=0)
+
+    @functools.wraps(fn)
+    def call(self, *args, **kwargs):
+        return jitted(self, self.wts, *args, **kwargs)
+
+    call.jitted = jitted
+    return call
+
+
 def _ln(x, s, b, eps=1e-5):
     mu = x.mean(-1, keepdims=True)
     var = x.var(-1, keepdims=True)
@@ -278,35 +298,38 @@ class JitIncrementalEngine:
             raise ValueError("delta_threshold must be >= 0")
         self.delta_threshold = float(delta_threshold)
         if _weights is not None:
-            self.W, self.extras, self.meta = _weights
+            W, extras, self.meta = _weights
         else:
-            self.W, self.extras, self.meta = _weights_from_params(params, cfg)
-        self.L = self.W["wq"].shape[0]
+            W, extras, self.meta = _weights_from_params(params, cfg)
+        # the device-resident weight pytree every jitted entry point takes
+        # as an argument (``jit_with_weights``)
+        self.wts = {"W": W, "extras": extras}
+        self.L = W["wq"].shape[0]
 
     @property
     def weights(self):
         """(W, extras, meta) — pass as ``_weights=`` to share the extracted
         parameter stacks between sibling engines (e.g. per-capacity-bucket
         re-jits in the batch server)."""
-        return self.W, self.extras, self.meta
+        return self.wts["W"], self.wts["extras"], self.meta
 
     # ------------------------------------------------------------ full pass
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def full_forward(self, tokens: jax.Array, positions: jax.Array,
+    @jit_with_weights
+    def full_forward(self, wts, tokens: jax.Array, positions: jax.Array,
                      valid: Optional[jax.Array] = None) -> JitState:
         """Ingest a slot buffer. ``valid=None`` means every slot is real (the
         plain fixed-length document of the replace-only path)."""
-        return self._full_forward_impl(tokens, positions, valid)
+        return self._full_forward_impl(wts, tokens, positions, valid)
 
-    def _full_forward_impl(self, tokens: jax.Array, positions: jax.Array,
+    def _full_forward_impl(self, wts, tokens: jax.Array, positions: jax.Array,
                            valid: Optional[jax.Array] = None) -> JitState:
         m = self.meta
         n = tokens.shape[0]
         if valid is None:
             valid = jnp.ones((n,), bool)
         valid = valid.astype(bool)
-        x0 = self.extras["tok_emb"][tokens] + self.extras["pos_emb"][positions]
+        x0 = wts["extras"]["tok_emb"][tokens] + wts["extras"]["pos_emb"][positions]
         causal, counts = _order_masks(positions, valid)
 
         def layer(x, Wl):
@@ -332,7 +355,7 @@ class JitIncrementalEngine:
         qs, ks, vs, vcs, Ts, cds = [], [], [], [], [], []
         x = x0
         for li in range(self.L):
-            Wl = jax.tree.map(lambda a: a[li], self.W)
+            Wl = jax.tree.map(lambda a: a[li], wts["W"])
             x, (q, k, v, vc, T, codes) = layer(x, Wl)
             xs.append(x)
             qs.append(q); ks.append(k); vs.append(v)
@@ -344,8 +367,8 @@ class JitIncrementalEngine:
 
     # ------------------------------------------------------------ edit step
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def apply_edits(self, state: JitState, slot: jax.Array, tok: jax.Array,
+    @jit_with_weights
+    def apply_edits(self, wts, state: JitState, slot: jax.Array, tok: jax.Array,
                     pos_id: jax.Array, op: jax.Array
                     ) -> tuple[JitState, jax.Array]:
         """The generic fixed-shape edit step: up to ``C`` typed edits at once.
@@ -363,34 +386,34 @@ class JitIncrementalEngine:
         (caller must full_forward). Overflow is detected on the PRE-gate
         changed set, so a ``delta_threshold`` never masks an overflow —
         thresholding only ever makes the flag conservative."""
-        return self._apply_edits_impl(state, slot, tok, pos_id, op)
+        return self._apply_edits_impl(wts, state, slot, tok, pos_id, op)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def apply_replaces(self, state: JitState, edit_pos: jax.Array,
+    @jit_with_weights
+    def apply_replaces(self, wts, state: JitState, edit_pos: jax.Array,
                        edit_tok: jax.Array) -> tuple[JitState, jax.Array]:
         """Replace-only bucket (back-compat surface). edit_pos: [C] int32
         slot indices (pad with -1); edit_tok: [C] int32."""
         z = jnp.zeros_like(edit_pos)
-        return self._apply_edits_impl(state, edit_pos, edit_tok, z, z)
+        return self._apply_edits_impl(wts, state, edit_pos, edit_tok, z, z)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def apply_inserts(self, state: JitState, slot: jax.Array, tok: jax.Array,
+    @jit_with_weights
+    def apply_inserts(self, wts, state: JitState, slot: jax.Array, tok: jax.Array,
                       pos_id: jax.Array) -> tuple[JitState, jax.Array]:
         """Insert-only bucket: claim free slots ``slot`` (pad with -1), give
         them tokens ``tok`` and fresh mid-gap position ids ``pos_id``."""
         op = jnp.where(slot >= 0, OP_INSERT, 0).astype(jnp.int32)
-        return self._apply_edits_impl(state, slot, tok, pos_id, op)
+        return self._apply_edits_impl(wts, state, slot, tok, pos_id, op)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def apply_deletes(self, state: JitState,
+    @jit_with_weights
+    def apply_deletes(self, wts, state: JitState,
                       slot: jax.Array) -> tuple[JitState, jax.Array]:
         """Delete-only bucket: invalidate slots ``slot`` (pad with -1) and
         subtract their column contributions."""
         z = jnp.zeros_like(slot)
         op = jnp.where(slot >= 0, OP_DELETE, 0).astype(jnp.int32)
-        return self._apply_edits_impl(state, slot, z, z, op)
+        return self._apply_edits_impl(wts, state, slot, z, z, op)
 
-    def _apply_edits_impl(self, state: JitState, slot: jax.Array,
+    def _apply_edits_impl(self, wts, state: JitState, slot: jax.Array,
                           tok: jax.Array, pos_id: jax.Array, op: jax.Array
                           ) -> tuple[JitState, jax.Array]:
         m = self.meta
@@ -432,8 +455,8 @@ class JitIncrementalEngine:
         vc_base = state.vc.at[:, ins_slot].set(0.0, mode="drop")
 
         # layer-0 dirty bucket = the edit bucket
-        x_rows = (self.extras["tok_emb"][tokens[slot_safe]]
-                  + self.extras["pos_emb"][positions[slot_safe]])
+        x_rows = (wts["extras"]["tok_emb"][tokens[slot_safe]]
+                  + wts["extras"]["pos_emb"][positions[slot_safe]])
         new_x = [state.x[0].at[jnp.where(has_new, slot, drop)].set(
             x_rows, mode="drop")]
         # rows to recompute this layer (gathered indices + occupancy mask)
@@ -451,7 +474,7 @@ class JitIncrementalEngine:
         overflow = jnp.asarray(False)
 
         for li in range(self.L):
-            Wl = jax.tree.map(lambda a: a[li], self.W)
+            Wl = jax.tree.map(lambda a: a[li], wts["W"])
             x_in = new_x[li]
             # per-location at dirty rows (garbage lanes are masked out below)
             h = _ln(x_in[dirty_idx], Wl["ln1_s"], Wl["ln1_b"])
@@ -688,18 +711,19 @@ class JitIncrementalEngine:
 
     # ------------------------------------------------------------ outputs
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def logits_last(self, state: JitState) -> jax.Array:
-        return self._logits_at_impl(state, -1)
+    @jit_with_weights
+    def logits_last(self, wts, state: JitState) -> jax.Array:
+        return self._logits_at_impl(wts, state, -1)
 
-    @functools.partial(jax.jit, static_argnums=0)
-    def logits_at(self, state: JitState, index: jax.Array) -> jax.Array:
+    @jit_with_weights
+    def logits_at(self, wts, state: JitState, index: jax.Array) -> jax.Array:
         """Logits at an arbitrary slot — the batched server pads documents to
         a capacity bucket, so "last token" is the slot holding the
         largest-position valid row (the host scheduler tracks it), not -1."""
-        return self._logits_at_impl(state, index)
+        return self._logits_at_impl(wts, state, index)
 
-    def _logits_at_impl(self, state: JitState, index: jax.Array) -> jax.Array:
-        h = _ln(state.x[-1][index][None], self.extras["fn_s"],
-                self.extras["fn_b"])[0]
-        return h @ self.extras["head_w"]
+    def _logits_at_impl(self, wts, state: JitState,
+                        index: jax.Array) -> jax.Array:
+        ex = wts["extras"]
+        h = _ln(state.x[-1][index][None], ex["fn_s"], ex["fn_b"])[0]
+        return h @ ex["head_w"]

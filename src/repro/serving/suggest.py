@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.bucketing import next_pow2
+from repro.common.precision import pinned_precision
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
 from repro.serving.decode import greedy_continue, make_serve_step
@@ -110,9 +111,12 @@ class SuggestionEngine:
         self.cfg = cfg
         self.default_new = int(default_new)
         self.dtype = dtype
-        self._step = jax.jit(make_serve_step(cfg, sample=False))
-        self._prefill = jax.jit(
-            lambda p, c, t, pos: T.prefill_step(p, cfg, t, c, pos))
+        # traced at the serving precision, like the engine whose exported
+        # k/v rows a refresh reuses
+        self._step = jax.jit(pinned_precision(
+            make_serve_step(cfg, sample=False)))
+        self._prefill = jax.jit(pinned_precision(
+            lambda p, c, t, pos: T.prefill_step(p, cfg, t, c, pos)))
         self._cache: dict = {}
         # residency listener (the state store's budget accounting): called
         # with (key, nbytes) whenever a document's persisted decode cache is

@@ -54,6 +54,10 @@ def test_batch_full_forward_matches_numpy_per_doc(setup):
 
 def test_batch_apply_replaces_matches_numpy_per_doc(setup):
     cfg, params, beng, neng = setup
+    # a row bucket as wide as the 40-token documents: no propagation can
+    # overflow it, so every slice is the incremental result itself
+    beng = BatchedJitEngine({}, cfg, edit_capacity=4, row_capacity=64,
+                            _weights=beng.weights)
     tokens, positions = _batch_docs(cfg, seed=1)
     bstate = beng.batch_full_forward(jnp.asarray(tokens), jnp.asarray(positions))
     nstates = [neng.full_forward(tokens[b], positions[b]) for b in range(3)]
@@ -277,3 +281,28 @@ def test_grow_reingest_does_not_race_host_mirrors(setup):
 def test_next_pow2():
     assert [next_pow2(n) for n in (1, 2, 3, 17, 64)] == [1, 2, 4, 32, 64]
     assert next_pow2(3, minimum=16) == 16
+
+
+def test_weights_are_arguments_not_constants(setup):
+    """No compiled serving program embeds a weight: the lowered batched edit
+    step takes every weight it reads as an argument, and holds no constant
+    the size of a weight."""
+    import re
+
+    cfg, params, beng, neng = setup
+    tokens, positions = _batch_docs(cfg, b=2)
+    state = beng.batch_full_forward(jnp.asarray(tokens), jnp.asarray(positions))
+    bucket = jnp.full((2, 4), -1, jnp.int32)
+    text = beng._batch_apply_edits_local.jitted.lower(
+        beng, beng.wts, state, bucket, bucket, bucket, bucket).as_text()
+    main = re.search(r"func\.func public @main\((.*?)\)\s*->", text, re.S)
+    signature = main.group(1)
+    for name in ("wq", "w_up", "w_down", "cb_per_head", "c_wo"):
+        dims = "x".join(map(str, beng.wts["W"][name].shape))
+        assert f"tensor<{dims}xf32>" in signature, name
+    for name in ("tok_emb", "pos_emb"):
+        dims = "x".join(map(str, beng.wts["extras"][name].shape))
+        assert f"tensor<{dims}xf32>" in signature, name
+    for dims in re.findall(r"stablehlo\.constant dense<[^>]*> : "
+                           r"tensor<([0-9x]+)x[a-z0-9]+>", text):
+        assert np.prod([int(x) for x in dims.split("x")]) < 1024, dims

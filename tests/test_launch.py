@@ -142,3 +142,22 @@ def test_serving_batch_sharding_prefix():
     assert tuple(s.spec) == ("data",)
     with pytest.raises(ValueError, match="no axis"):
         serving_batch_sharding(mesh, axis="nope")
+
+
+def test_chip_peaks_one_table_unknown_device_raises():
+    """One peak table keyed by ``device_kind``: the v5e entry is the
+    published figures, and a device the table does not know is an error,
+    never a default."""
+    from repro.launch.roofline import chip_peaks, edit_step_roofline
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert v5e["flops"] == 197e12 and v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(ValueError, match="no peak figures"):
+        chip_peaks("cpu")
+    meta = dict(d=256, H=4, dh=64, Q=64, hq=2)
+    with pytest.raises(ValueError):
+        edit_step_roofline(2, meta, 64, 8, 16, xla_flops=1.0, xla_bytes=1.0,
+                           device_kind="TPU v99")
+    rl = edit_step_roofline(2, meta, 64, 8, 16, xla_flops=1.0, xla_bytes=1.0,
+                            device_kind="TPU v5 lite")
+    assert rl.peak_flops == v5e["flops"]

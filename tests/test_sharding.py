@@ -60,7 +60,10 @@ def test_mini_dryrun_subprocess():
         out = {}
         for axes, shape in [(("data", "model"), (2, 4)),
                             (("pod", "data", "model"), (2, 2, 2))]:
-            mesh = jax.make_mesh(shape, axes)
+            # Auto axes: the model code places values with
+            # with_sharding_constraint (make_mesh defaults to Explicit)
+            mesh = jax.make_mesh(
+                shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
             cfg = get_config("deepseek-v2-236b", smoke=True)
             with use_mesh(mesh):
                 state_sds = jax.eval_shape(
@@ -73,15 +76,15 @@ def test_mini_dryrun_subprocess():
                     batch_shardings(batch_sds, mesh))).lower(state_sds, batch_sds)
                 compiled = lowered.compile()
                 ca = compiled.cost_analysis()
-                if isinstance(ca, (list, tuple)):  # older jax: one per device
-                    ca = ca[0]
                 out["x".join(map(str, shape))] = float(ca.get("flops", 0))
         print(json.dumps(out))
         """
     )
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    # the forced host devices are CPU devices: keep the child off the TPU
+    # library, which a parent test process may already hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", code], capture_output=True, text=True, env=env,
         timeout=600,
