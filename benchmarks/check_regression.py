@@ -88,9 +88,6 @@ GATES = {
                                      "rel_tol": 0.15, "optional": True},
             "compiled_shapes_structural_stream": {
                 "higher_is_better": False, "abs_tol": 0, "optional": True},
-            "kernel_launches_per_edit": {
-                "higher_is_better": False, "abs_tol": 0.25,
-                "optional": True},
             "device_grows": {"higher_is_better": True, "abs_tol": 0,
                              "optional": True},
         },

@@ -136,7 +136,6 @@ def run(doc_len: int = 192, n_edits: int = 24, n_docs: int = 4,
             srv.flush()
         _sync(srv)
         warm_shapes = srv.stats.traced_shapes
-        launches0 = srv.stats.kernel_launches
         t0 = time.perf_counter()
         for did, op, pos, tok in trace:  # measured pass, same trace
             srv.submit_edit(did, Edit(op, pos, tok))
@@ -161,8 +160,6 @@ def run(doc_len: int = 192, n_edits: int = 24, n_docs: int = 4,
             "traced_shapes": srv.stats.traced_shapes,
             "measured_pass_new_shapes":
                 srv.stats.traced_shapes - warm_shapes,
-            "kernel_launches_per_edit": round(
-                (srv.stats.kernel_launches - launches0) / n_edits, 3),
             "overflows": srv.stats.overflows,
             "defrags": srv.stats.defrags,
             "device_defrags": srv.stats.device_defrags,
@@ -172,8 +169,7 @@ def run(doc_len: int = 192, n_edits: int = 24, n_docs: int = 4,
         records.append(rec)
         print(f"edit_mix,{name},ops_speedup={rec['ops_speedup']},"
               f"wall_per_edit_ms={rec['wall_s_per_edit']*1e3:.2f},"
-              f"traced_shapes={rec['traced_shapes']},"
-              f"launches_per_edit={rec['kernel_launches_per_edit']}")
+              f"traced_shapes={rec['traced_shapes']}")
     # the CI-gated fusion metric: how much slower a structural stream is
     # than the replace-only fast path, warm, on the same server config
     by_name = {r["workload"]: r for r in records}

@@ -116,8 +116,6 @@ def _structural_shape_count(params, cfg, *, n_edits: int, seed: int,
         srv.flush()
     return {
         "compiled_shapes_structural_stream": srv.stats.traced_shapes,
-        "kernel_launches_per_edit": round(
-            srv.stats.kernel_launches / max(srv.stats.edits_applied, 1), 3),
         "device_grows": srv.stats.device_grows,
         "device_defrags": srv.stats.device_defrags,
     }
@@ -172,8 +170,7 @@ def run(doc_len: int = 64, n_edits: int = 24, seed: int = 0,
                   f"useful_flop_fraction={r['useful_flop_fraction']}")
         else:
             print(f"hot_path,{r['workload']},"
-                  f"shapes={r['compiled_shapes_structural_stream']},"
-                  f"launches_per_edit={r['kernel_launches_per_edit']}")
+                  f"shapes={r['compiled_shapes_structural_stream']}")
     _merge_write(records)
     return records
 

@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.core.edits import Edit
 from repro.serving.batch_server import BatchServer
+from repro.serving.trace import phase
 
 
 class Ticket:
@@ -147,6 +148,15 @@ class AsyncStats:
     admitted_suggests: int = 0
     admitted_opens: int = 0
     requests_failed: int = 0  # tickets resolved with an exception
+    # ---- the scheduler thread's time by phase (ns, ``serving.trace``;
+    # span ``serve.async.<phase>``). The five partition the thread's time
+    idle_ns: int = 0  # waiting with no request queued
+    batching_ns: int = 0  # waiting for the deadline or for a full bucket
+    admit_ns: int = 0  # phase 1: admissions into the inner server's queues
+    flush_ns: int = 0  # phase 2: the inner server's flush()
+    deliver_ns: int = 0  # phase 3: acks, suggestion tickets, subscriptions
+    queue_wait_ns: int = 0  # over admitted edits: admission to the start
+    # of its round's flush
 
     @property
     def mean_edits_per_round(self) -> float:
@@ -293,30 +303,36 @@ class AsyncBatchServer:
 
     def _loop(self) -> None:
         while True:
+            rnd = self.stats.rounds + 1
             with self._cond:
-                while not self._requests and not self._stop:
-                    self._cond.wait()
+                with phase(self.stats, "idle_ns", "serve.async.idle",
+                           round=rnd):
+                    while not self._requests and not self._stop:
+                        self._cond.wait()
                 if not self._requests:  # stopping, fully drained
                     break
                 full = False
-                if not self._stop:  # draining rounds skip the deadline wait
-                    deadline = (self._requests[0][1].admit_t
-                                + self.max_batch_delay_ms / 1e3)
-                    while not self._stop:
-                        if self._ready_docs() >= self.bucket_docs:
-                            full = True
-                            break
-                        remaining = deadline - time.perf_counter()
-                        if remaining <= 0:
-                            break
-                        self._cond.wait(remaining)
-                batch = list(self._requests)
-                self._requests.clear()
+                with phase(self.stats, "batching_ns", "serve.async.batching",
+                           round=rnd):
+                    if not self._stop:  # draining rounds skip the wait
+                        deadline = (self._requests[0][1].admit_t
+                                    + self.max_batch_delay_ms / 1e3)
+                        while not self._stop:
+                            if self._ready_docs() >= self.bucket_docs:
+                                full = True
+                                break
+                            remaining = deadline - time.perf_counter()
+                            if remaining <= 0:
+                                break
+                            self._cond.wait(remaining)
+                    batch = list(self._requests)
+                    self._requests.clear()
             self._run_round(batch, full)
 
     def _run_round(self, batch: list, full: bool) -> None:
         srv = self.server
         self.stats.rounds += 1
+        rnd = self.stats.rounds
         if full:
             self.stats.full_rounds += 1
         else:
@@ -353,104 +369,119 @@ class AsyncBatchServer:
                         t._fail(e)
             pending_opens.clear()
 
-        for kind, ticket, payload in batch:
-            try:
-                if kind == "open":
-                    pending_opens[ticket.doc_id] = (ticket, payload)
-                    continue
-                flush_opens()
-                if kind == "edit":
-                    op, pos, tok = payload
-                    if op == "replace":
-                        srv.submit_replace(ticket.doc_id, pos, tok)
-                    elif op == "insert":
-                        srv.submit_insert(ticket.doc_id, pos, tok)
-                    else:
-                        srv.submit_delete(ticket.doc_id, pos)
-                    edit_tickets.append(ticket)
-                elif kind == "suggest":
-                    srv.submit_suggest(ticket.doc_id, payload)
-                    suggest_reqs.append((ticket, ticket.doc_id, payload))
-                elif kind == "subscribe":
-                    srv.submit_suggest(ticket.doc_id, payload.n_new)
-                    ticket._resolve()
-                elif kind == "close":
-                    self._close_streams(ticket.doc_id)
-                    srv.close_document(ticket.doc_id)
-                    ticket._resolve()
-                elif kind == "tokens":
-                    ticket._resolve(srv.tokens(ticket.doc_id))
-                elif kind == "barrier":
-                    barriers.append(ticket)
-                else:  # pragma: no cover - admission kinds are internal
-                    raise AssertionError(f"unknown request kind {kind!r}")
-            except Exception as e:
-                self.stats.requests_failed += 1
-                ticket._fail(e)
-        flush_opens()
+        with phase(self.stats, "admit_ns", "serve.async.admit", round=rnd):
+            for kind, ticket, payload in batch:
+                try:
+                    if kind == "open":
+                        pending_opens[ticket.doc_id] = (ticket, payload)
+                        continue
+                    flush_opens()
+                    if kind == "edit":
+                        op, pos, tok = payload
+                        if op == "replace":
+                            srv.submit_replace(ticket.doc_id, pos, tok)
+                        elif op == "insert":
+                            srv.submit_insert(ticket.doc_id, pos, tok)
+                        else:
+                            srv.submit_delete(ticket.doc_id, pos)
+                        edit_tickets.append(ticket)
+                    elif kind == "suggest":
+                        srv.submit_suggest(ticket.doc_id, payload)
+                        suggest_reqs.append((ticket, ticket.doc_id, payload))
+                    elif kind == "subscribe":
+                        srv.submit_suggest(ticket.doc_id, payload.n_new)
+                        ticket._resolve()
+                    elif kind == "close":
+                        self._close_streams(ticket.doc_id)
+                        srv.close_document(ticket.doc_id)
+                        ticket._resolve()
+                    elif kind == "tokens":
+                        ticket._resolve(srv.tokens(ticket.doc_id))
+                    elif kind == "barrier":
+                        barriers.append(ticket)
+                    else:  # pragma: no cover - admission kinds are internal
+                        raise AssertionError(f"unknown request kind {kind!r}")
+                except Exception as e:
+                    self.stats.requests_failed += 1
+                    ticket._fail(e)
+            flush_opens()
+            serials = {d_id: d.suggest_serial for d_id, d in srv.docs.items()}
 
         # ---- phase 2: one synchronous scheduling drain. flush() groups the
         # coalesced per-document queues into capacity-bucketed dispatches
         # and refreshes every stale subscription (snapshot/rollback and the
         # oracle guarantees are the inner scheduler's, untouched).
-        serials = {d_id: d.suggest_serial for d_id, d in srv.docs.items()}
-        try:
-            srv.flush()
-        except Exception as e:
-            # dispatch failure: the inner scheduler rolled every affected
-            # document back and KEPT its queued edits, so the work retries
-            # with the next round; these tickets report the failure
-            for t in edit_tickets:
-                self.stats.requests_failed += 1
-                t._fail(e)
-            for t, _, _ in suggest_reqs:
-                self.stats.requests_failed += 1
-                t._fail(e)
-            for t in barriers:
-                t._fail(e)
-            return
-
-        now = time.perf_counter()
-        for t in edit_tickets:
-            srv.stats.edit_latency.record((now - t.admit_t) * 1e3)
-            t._resolve()
-        self.stats.admitted_edits += len(edit_tickets)
-
-        for t, doc_id, n_new in suggest_reqs:
+        error = None
+        with phase(self.stats, "flush_ns", "serve.async.flush",
+                   round=rnd) as flushing:
             try:
-                out = srv.suggest(doc_id, n_new)  # fresh -> cached, no work
+                srv.flush()
             except Exception as e:
-                self.stats.requests_failed += 1
-                t._fail(e)
-                continue
-            srv.stats.suggest_latency.record(
-                (time.perf_counter() - t.admit_t) * 1e3)
-            t._resolve(out)
-        self.stats.admitted_suggests += len(suggest_reqs)
+                error = e
 
-        # ---- phase 3: deliver refreshed subscriptions. Token events were
-        # already streamed live from the decode loop; completed
-        # continuations are pushed here, and edit-triggered refreshes (no
-        # explicit suggest ticket) record their latency from the round's
-        # oldest admission — the queueing delay is part of the SLO.
-        round_t0 = min((t.admit_t for _, t, _ in batch), default=now)
-        explicit = {doc_id for _, doc_id, _ in suggest_reqs}
-        with self._subs_lock:
-            subscribed = {d: list(ss) for d, ss in self._subs.items()}
-        for doc_id, streams in subscribed.items():
-            doc = srv.docs.get(doc_id)
-            if doc is None or not doc.suggest_fresh:
-                continue
-            if doc.suggest_serial == serials.get(doc_id):
-                continue  # nothing new since the last delivery
-            if doc_id not in explicit:
+        with phase(self.stats, "deliver_ns", "serve.async.deliver",
+                   round=rnd):
+            if error is not None:
+                # dispatch failure: the inner scheduler rolled every
+                # affected document back and KEPT its queued edits, so the
+                # work retries with the next round; these tickets report
+                # the failure
+                for t in edit_tickets:
+                    self.stats.requests_failed += 1
+                    t._fail(error)
+                for t, _, _ in suggest_reqs:
+                    self.stats.requests_failed += 1
+                    t._fail(error)
+                for t in barriers:
+                    t._fail(error)
+                return
+
+            now = time.perf_counter()
+            for t in edit_tickets:
+                srv.stats.edit_latency.record((now - t.admit_t) * 1e3)
+                self.stats.queue_wait_ns += (flushing.start_ns
+                                             - int(t.admit_t * 1e9))
+                t._resolve()
+            self.stats.admitted_edits += len(edit_tickets)
+
+            for t, doc_id, n_new in suggest_reqs:
+                try:
+                    # fresh -> cached, no work
+                    out = srv.suggest(doc_id, n_new)
+                except Exception as e:
+                    self.stats.requests_failed += 1
+                    t._fail(e)
+                    continue
                 srv.stats.suggest_latency.record(
-                    (time.perf_counter() - round_t0) * 1e3)
-            event = ("suggestion", doc.suggest_serial, doc.suggestion.copy())
-            for s in streams:
-                s._push(event)
-        for t in barriers:
-            t._resolve()
+                    (time.perf_counter() - t.admit_t) * 1e3)
+                t._resolve(out)
+            self.stats.admitted_suggests += len(suggest_reqs)
+
+            # ---- phase 3: deliver refreshed subscriptions. Token events
+            # were already streamed live from the decode loop; completed
+            # continuations are pushed here, and edit-triggered refreshes
+            # (no explicit suggest ticket) record their latency from the
+            # round's oldest admission — the queueing delay is part of the
+            # SLO.
+            round_t0 = min((t.admit_t for _, t, _ in batch), default=now)
+            explicit = {doc_id for _, doc_id, _ in suggest_reqs}
+            with self._subs_lock:
+                subscribed = {d: list(ss) for d, ss in self._subs.items()}
+            for doc_id, streams in subscribed.items():
+                doc = srv.docs.get(doc_id)
+                if doc is None or not doc.suggest_fresh:
+                    continue
+                if doc.suggest_serial == serials.get(doc_id):
+                    continue  # nothing new since the last delivery
+                if doc_id not in explicit:
+                    srv.stats.suggest_latency.record(
+                        (time.perf_counter() - round_t0) * 1e3)
+                event = ("suggestion", doc.suggest_serial,
+                         doc.suggestion.copy())
+                for s in streams:
+                    s._push(event)
+            for t in barriers:
+                t._resolve()
 
     # ------------------------------------------------------------- streaming
 

@@ -66,13 +66,18 @@ from repro.serving.jit_engine import (
 BatchedJitState = JitState
 
 
+@jax.jit
 def stack_states(states: list[JitState]) -> BatchedJitState:
-    """Stack per-document states along a new leading batch axis."""
+    """Stack per-document states along a new leading batch axis. One jitted
+    program per (batch size, state shape), named ``jit_stack_states`` in a
+    profile: the whole-state copy each dispatch makes on the way in."""
     return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
 
 
+@jax.jit
 def unstack_state(batched: BatchedJitState, b: int) -> JitState:
-    """Slice document ``b`` back out of a batched state."""
+    """Slice document ``b`` back out of a batched state. ``b`` is traced, so
+    one program (``jit_unstack_state``) serves every row of a batch."""
     return jax.tree.map(lambda x: x[b], batched)
 
 

@@ -86,6 +86,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -108,6 +109,7 @@ from repro.serving.state_store import StateStore
 from repro.serving.suggest import (
     PositionHeadroomError, SuggestionEngine, SuggestStats,
 )
+from repro.serving.trace import phase
 
 
 _OPCODE = {"replace": OP_REPLACE, "insert": OP_INSERT, "delete": OP_DELETE}
@@ -148,9 +150,6 @@ class BatchStats:
     device_grows: int = 0  # grows served by the device-side pad_state path
     # (no full-forward re-ingest — existing slots keep their bits)
     rejits: int = 0  # distinct dispatch shapes traced
-    kernel_launches: int = 0  # device program launches on the edit path
-    # (edit dispatches, ingests/re-ingests, device pads/gathers) — the
-    # per-edit launch budget of the fused hot path
     suggest_refreshes: int = 0  # suggestion recomputes served
     suggest_invalidations: int = 0  # fresh suggestions staled by newer edits
     suggest_cached_hits: int = 0  # suggestions served from the cached
@@ -185,6 +184,16 @@ class BatchStats:
     # ---- cross-process migration (fleet serving, DESIGN.md §11)
     exports: int = 0  # export_document calls (doc handed off to a snapshot)
     imports: int = 0  # import_document calls (doc adopted from a snapshot)
+    # ---- the scheduler's time by phase (ns, ``serving.trace``; span
+    # ``serve.batch.<phase>``). Nested phases count once, so inside a
+    # flush() they sum to at most its time
+    take_ns: int = 0  # snapshots, bucket takes and grouping in step()
+    stack_ns: int = 0  # rehydration, stacking and bucket uploads
+    launch_ns: int = 0  # the batched edit step's call, until it returns
+    sync_ns: int = 0  # the host waiting for the step's overflow flags
+    adopt_ns: int = 0  # per-document unstacking and state adoption
+    reingest_ns: int = 0  # overflow re-ingests, defrags and grows
+    refresh_ns: int = 0  # suggestion refreshes
 
     @property
     def mean_batch(self) -> float:
@@ -198,14 +207,6 @@ class BatchStats:
         tests/test_mixed_edit_streams.py). Alias of ``rejits`` under the
         name the benchmarks report."""
         return self.rejits
-
-    @property
-    def kernel_launches_per_edit(self) -> float:
-        """Edit-path device program launches per applied edit. The fused
-        hot path's first-class wall-clock proxy: one launch per dispatch,
-        amortized over its whole bucket, with slow paths (re-ingests,
-        device pads/gathers) surfacing as fractional overhead."""
-        return self.kernel_launches / max(self.edits_applied, 1)
 
     @property
     def hot_hit_rate(self) -> float:
@@ -339,6 +340,7 @@ class BatchServer:
         self._engines: dict[tuple[int, int], BatchedJitEngine] = {
             (self.C, self.R): base}
         self._shapes_seen: set = set()
+        self._step = 0  # step() calls: the ``step`` id of batch spans
         self.docs: dict[str, _BatchDoc] = {}
         self.stats = BatchStats()
         self._sugg: Optional[SuggestionEngine] = None
@@ -524,7 +526,6 @@ class BatchServer:
                 bstate = eng.batch_full_forward(
                     jnp.asarray(toks), jnp.asarray(poss), jnp.asarray(vals))
                 self._count_shape(("full", B_pad, n_cap))
-                self.stats.kernel_launches += 1
                 self._note_balance(loads)
                 for b, i in enumerate(rows):
                     if i is None:
@@ -540,6 +541,10 @@ class BatchServer:
                     self.store.register(doc)
                     self.stats.docs += 1
                     self.stats.full_forwards += 1
+                # one chunk at a time: a host that queued every chunk's
+                # full forward ahead of the device would hold all their
+                # batched results and slices at once
+                jax.block_until_ready(bstate)
 
     def close_document(self, doc_id: str) -> None:
         """End a session: release the document's slot rows, allocator,
@@ -786,22 +791,25 @@ class BatchServer:
         takes = []  # (doc, kind, arrays, count)
         undone: dict[int, tuple] = {}  # id(doc) -> (doc, snapshot)
         applied = 0
+        self._step += 1
         self._in_round = True
         try:
-            for d in ready:
-                snap = self._snapshot(d)
-                undone[id(d)] = (d, snap)
-                kind, arrays, count = self._take_bucket(d)
-                if count == 0:
-                    self._restore(d, snap)
-                    undone.pop(id(d))
-                    continue
-                takes.append((d, kind, arrays, count))
-            groups: dict[tuple, list] = {}
-            for t in takes:
-                groups.setdefault(
-                    (t[0].n_cap, self.C, t[0].row_capacity, t[1]),
-                    []).append(t)
+            with phase(self.stats, "take_ns", "serve.batch.take",
+                       step=self._step, docs=len(ready)):
+                for d in ready:
+                    snap = self._snapshot(d)
+                    undone[id(d)] = (d, snap)
+                    kind, arrays, count = self._take_bucket(d)
+                    if count == 0:
+                        self._restore(d, snap)
+                        undone.pop(id(d))
+                        continue
+                    takes.append((d, kind, arrays, count))
+                groups: dict[tuple, list] = {}
+                for t in takes:
+                    groups.setdefault(
+                        (t[0].n_cap, self.C, t[0].row_capacity, t[1]),
+                        []).append(t)
             for (n_cap, C, R, kind), members in sorted(groups.items(),
                                                        key=lambda kv: kv[0]):
                 for lo in range(0, len(members), self.max_batch):
@@ -838,77 +846,94 @@ class BatchServer:
         docs = [t[0] for t in chunk]
         buckets = [t[2] for t in chunk]
         counts = [t[3] for t in chunk]
-        # transparent rehydration on touch: every chunk member must be hot
-        # for the stacked dispatch — warm/cold members re-upload their
-        # snapshots (bit-exact), protected from each other's admissions
-        keep = frozenset(d.doc_id for d in docs)
-        for d in docs:
-            self.store.ensure_hot(d, keep=keep)
-        # pad to a pow2 batch (multiple of the mesh's batch axis) with copies
-        # of doc 0 carrying empty edit buckets (all -1): no-op slices whose
-        # output is discarded. Members are placed to balance dirty-slot work
-        # across the contiguous per-shard row blocks.
-        B_pad = self._padded_batch(len(chunk))
-        rows, loads = self._place_rows(counts, B_pad)
-        empty = (np.full(C, -1, np.int32), np.zeros(C, np.int32),
-                 np.zeros(C, np.int32), np.zeros(C, np.int32))
-        row_buckets = [buckets[i] if i is not None else empty for i in rows]
-        states = [docs[i].state if i is not None else docs[0].state
-                  for i in rows]
-        slot = jnp.asarray(np.stack([b[0] for b in row_buckets]))
-        tok = jnp.asarray(np.stack([b[1] for b in row_buckets]))
-        pos = jnp.asarray(np.stack([b[2] for b in row_buckets]))
-        batched = stack_states(states)
-        if kind == "replace":
-            new_state, overflow = eng.batch_apply_replaces(batched, slot, tok)
-        elif kind == "insert":
-            new_state, overflow = eng.batch_apply_inserts(batched, slot, tok,
-                                                          pos)
-        else:
-            new_state, overflow = eng.batch_apply_deletes(batched, slot)
-        overflow = np.asarray(overflow)
-        self.stats.batch_steps += 1
-        self.stats.batched_docs += len(chunk)
-        # all three op kinds share one compiled step per (B, n_cap, C, R):
-        # the op vector is data, so `kind` is NOT part of the traced shape
-        self._count_shape(("edit", B_pad, n_cap, C, R))
-        self.stats.kernel_launches += 1
-        self._note_balance(loads)
-        applied = 0
-        for b, i in enumerate(rows):
-            if i is None:
-                continue
-            doc = docs[i]
-            applied += counts[i]
-            self.stats.edits_applied += counts[i]
-            if overflow[b]:
-                self._fallback_full_forward(doc)
+        ids = dict(step=self._step, dispatch=self.stats.batch_steps + 1,
+                   docs=len(chunk), R=R)
+        with phase(self.stats, "stack_ns", "serve.batch.stack", **ids):
+            # transparent rehydration on touch: every chunk member must be
+            # hot for the stacked dispatch — warm/cold members re-upload
+            # their snapshots (bit-exact), protected from each other's
+            # admissions
+            keep = frozenset(d.doc_id for d in docs)
+            for d in docs:
+                self.store.ensure_hot(d, keep=keep)
+            # pad to a pow2 batch (multiple of the mesh's batch axis) with
+            # copies of doc 0 carrying empty edit buckets (all -1): no-op
+            # slices whose output is discarded. Members are placed to
+            # balance dirty-slot work across the contiguous per-shard row
+            # blocks.
+            B_pad = self._padded_batch(len(chunk))
+            rows, loads = self._place_rows(counts, B_pad)
+            empty = (np.full(C, -1, np.int32), np.zeros(C, np.int32),
+                     np.zeros(C, np.int32), np.zeros(C, np.int32))
+            row_buckets = [buckets[i] if i is not None else empty
+                           for i in rows]
+            states = [docs[i].state if i is not None else docs[0].state
+                      for i in rows]
+            slot = jnp.asarray(np.stack([b[0] for b in row_buckets]))
+            tok = jnp.asarray(np.stack([b[1] for b in row_buckets]))
+            pos = jnp.asarray(np.stack([b[2] for b in row_buckets]))
+            batched = stack_states(states)
+            # the queued programs hold their own inputs until they have
+            # run; a reference kept here would hold the replaced states and
+            # the stacked input through the adoption and its re-ingests
+            del states
+        with phase(self.stats, "launch_ns", "serve.batch.launch", **ids):
+            if kind == "replace":
+                new_state, overflow = eng.batch_apply_replaces(batched, slot,
+                                                               tok)
+            elif kind == "insert":
+                new_state, overflow = eng.batch_apply_inserts(batched, slot,
+                                                              tok, pos)
             else:
-                self.store.set_hot(doc, unstack_state(new_state, b))
+                new_state, overflow = eng.batch_apply_deletes(batched, slot)
+            del batched
+        with phase(self.stats, "sync_ns", "serve.batch.sync", **ids):
+            overflow = np.asarray(overflow)
+        with phase(self.stats, "adopt_ns", "serve.batch.adopt", **ids):
+            self.stats.batch_steps += 1
+            self.stats.batched_docs += len(chunk)
+            # all three op kinds share one compiled step per (B, n_cap, C,
+            # R): the op vector is data, so `kind` is NOT part of the
+            # traced shape
+            self._count_shape(("edit", B_pad, n_cap, C, R))
+            self._note_balance(loads)
+            applied = 0
+            for b, i in enumerate(rows):
+                if i is None:
+                    continue
+                doc = docs[i]
+                applied += counts[i]
+                self.stats.edits_applied += counts[i]
+                if overflow[b]:
+                    self._fallback_full_forward(doc)
+                else:
+                    self.store.set_hot(doc, unstack_state(new_state, b))
         return applied
 
     # ------------------------------------------------------------ slow paths
 
     def _reingest(self, doc: _BatchDoc) -> None:
         """Rebuild device state from the host mirrors (one full forward)."""
-        eng = self.engine(self.C, self.R)
-        # admit the replacement state up front (a grown buffer is bigger
-        # than the one it replaces; an evicted doc brings wholly new bytes)
-        new_bytes = state_nbytes_for(doc.n_cap, eng.L, eng.meta)
-        resident = (self.store.nbytes(doc.doc_id)
-                    if self.store.tier(doc.doc_id) == "hot" else 0)
-        self.store.admit(max(new_bytes - resident, 0),
-                         keep=frozenset((doc.doc_id,)))
-        state = eng.full_forward(_device_copy(doc.tokens),
-                                 _device_copy(doc.positions),
-                                 _device_copy(doc.valid))
-        self.store.set_hot(doc, state)
-        # the state is a from-scratch full forward again: every exported
-        # column is trustworthy for suggestion KV reuse
-        doc.touched_from = None
-        self.stats.full_forwards += 1
-        self.stats.kernel_launches += 1
-        self._count_shape(("full", doc.n_cap))
+        with phase(self.stats, "reingest_ns", "serve.batch.reingest",
+                   step=self._step, doc=doc.doc_id):
+            eng = self.engine(self.C, self.R)
+            # admit the replacement state up front (a grown buffer is bigger
+            # than the one it replaces; an evicted doc brings wholly new
+            # bytes)
+            new_bytes = state_nbytes_for(doc.n_cap, eng.L, eng.meta)
+            resident = (self.store.nbytes(doc.doc_id)
+                        if self.store.tier(doc.doc_id) == "hot" else 0)
+            self.store.admit(max(new_bytes - resident, 0),
+                             keep=frozenset((doc.doc_id,)))
+            state = eng.full_forward(_device_copy(doc.tokens),
+                                     _device_copy(doc.positions),
+                                     _device_copy(doc.valid))
+            self.store.set_hot(doc, state)
+            # the state is a from-scratch full forward again: every exported
+            # column is trustworthy for suggestion KV reuse
+            doc.touched_from = None
+            self.stats.full_forwards += 1
+            self._count_shape(("full", doc.n_cap))
 
     def _fallback_full_forward(self, doc: _BatchDoc) -> None:
         """Overflow: discard the unreliable batched slice, recompute from the
@@ -928,33 +953,34 @@ class BatchServer:
         survives, so ``touched_from`` is deliberately NOT cleared. The first
         dispatch in the bigger class re-jits — amortized across the
         fleet."""
-        old_cap, new_cap = doc.n_cap, self.padded_cap(doc.n_cap + 1)
-        for name, fill in (("tokens", 0), ("valid", False),
-                           ("positions", self._pos_sentinel)):
-            arr = getattr(doc, name)
-            grown = np.full(new_cap, fill, arr.dtype)
-            grown[:old_cap] = arr
-            setattr(doc, name, grown)
-        doc.free.extend(range(new_cap - 1, old_cap - 1, -1))
-        doc.n_cap = new_cap
-        self.stats.grows += 1
-        if self._sugg is not None:  # capacity changed: cache shape unusable
-            self._sugg.drop(doc.doc_id)
-        if not self.device_grow:
-            self._reingest(doc)
-            return
-        eng = self.engine(self.C, self.R)
-        state = self.store.ensure_hot(doc, keep=frozenset((doc.doc_id,)))
-        self.store.admit(
-            state_nbytes_for(new_cap, eng.L, eng.meta)
-            - state_nbytes_for(old_cap, eng.L, eng.meta),
-            keep=frozenset((doc.doc_id,)))
-        new_state = eng.pad_state(state, new_cap,
-                                  pos_fill=self._pos_sentinel)
-        self.store.set_hot(doc, new_state)
-        self.stats.device_grows += 1
-        self.stats.kernel_launches += 1
-        self._count_shape(("pad", old_cap, new_cap))
+        with phase(self.stats, "reingest_ns", "serve.batch.grow",
+                   step=self._step, doc=doc.doc_id):
+            old_cap, new_cap = doc.n_cap, self.padded_cap(doc.n_cap + 1)
+            for name, fill in (("tokens", 0), ("valid", False),
+                               ("positions", self._pos_sentinel)):
+                arr = getattr(doc, name)
+                grown = np.full(new_cap, fill, arr.dtype)
+                grown[:old_cap] = arr
+                setattr(doc, name, grown)
+            doc.free.extend(range(new_cap - 1, old_cap - 1, -1))
+            doc.n_cap = new_cap
+            self.stats.grows += 1
+            if self._sugg is not None:  # capacity changed: the cache's
+                self._sugg.drop(doc.doc_id)  # shape is unusable
+            if not self.device_grow:
+                self._reingest(doc)
+                return
+            eng = self.engine(self.C, self.R)
+            state = self.store.ensure_hot(doc, keep=frozenset((doc.doc_id,)))
+            self.store.admit(
+                state_nbytes_for(new_cap, eng.L, eng.meta)
+                - state_nbytes_for(old_cap, eng.L, eng.meta),
+                keep=frozenset((doc.doc_id,)))
+            new_state = eng.pad_state(state, new_cap,
+                                      pos_fill=self._pos_sentinel)
+            self.store.set_hot(doc, new_state)
+            self.stats.device_grows += 1
+            self._count_shape(("pad", old_cap, new_cap))
 
     def _defrag(self, doc: _BatchDoc) -> None:
         """Gap exhaustion: re-spread every position id evenly (paper §3.3,
@@ -967,47 +993,49 @@ class BatchServer:
         ``full_forward`` a re-ingest would run — bitwise-identical output
         by construction (tested against the host re-ingest oracle in
         tests/test_fused_step.py)."""
-        self.stats.defrags += 1
-        if self._sugg is not None:  # every position id changed: nothing in
-            self._sugg.drop(doc.doc_id)  # the doc's decode cache is reusable
-        doc.invalid_from = 0
-        self._stale(doc)
-        if not self.device_defrag:
+        with phase(self.stats, "reingest_ns", "serve.batch.defrag",
+                   step=self._step, doc=doc.doc_id):
+            self.stats.defrags += 1
+            if self._sugg is not None:  # every position id changed: nothing
+                self._sugg.drop(doc.doc_id)  # in the decode cache is reusable
+            doc.invalid_from = 0
+            self._stale(doc)
+            if not self.device_defrag:
+                doc.allocator.defragment()
+                doc.positions[np.asarray(doc.slots, np.int64)] = \
+                    doc.allocator.snapshot()
+                self._reingest(doc)
+                return
+            eng = self.engine(self.C, self.R)
+            state = self.store.ensure_hot(doc, keep=frozenset((doc.doc_id,)))
+            n = doc.n
+            # compaction permutation: live slots in sequence order first,
+            # then the free tail — slot i of the permuted buffers is token i
+            # of the document, so the re-spread ids land 1:1
+            order = np.concatenate([np.asarray(doc.slots, np.int32),
+                                    np.asarray(doc.free, np.int32)])
             doc.allocator.defragment()
-            doc.positions[np.asarray(doc.slots, np.int64)] = \
-                doc.allocator.snapshot()
-            self._reingest(doc)
-            return
-        eng = self.engine(self.C, self.R)
-        state = self.store.ensure_hot(doc, keep=frozenset((doc.doc_id,)))
-        n = doc.n
-        # compaction permutation: live slots in sequence order first, then
-        # the free tail — slot i of the permuted buffers is token i of the
-        # document, so the re-spread ids land 1:1
-        order = np.concatenate([np.asarray(doc.slots, np.int32),
-                                np.asarray(doc.free, np.int32)])
-        doc.allocator.defragment()
-        respread = doc.allocator.snapshot()
-        permuted = eng.gather_slots(state, jnp.asarray(order))
-        new_positions = np.full(doc.n_cap, self._pos_sentinel, np.int32)
-        new_positions[:n] = respread
-        new_valid = np.zeros(doc.n_cap, bool)
-        new_valid[:n] = True
-        new_state = eng.full_forward(permuted.tokens,
-                                     _device_copy(new_positions),
-                                     _device_copy(new_valid))
-        self.store.set_hot(doc, new_state)
-        # host mirrors follow the compaction so slot indices keep matching
-        doc.tokens = doc.tokens[order]
-        doc.valid = new_valid
-        doc.positions = new_positions
-        doc.slots = list(range(n))
-        doc.free = list(range(doc.n_cap - 1, n - 1, -1))
-        doc.touched_from = None
-        self.stats.device_defrags += 1
-        self.stats.full_forwards += 1
-        self.stats.kernel_launches += 2
-        self._count_shape(("full", doc.n_cap))
+            respread = doc.allocator.snapshot()
+            permuted = eng.gather_slots(state, jnp.asarray(order))
+            new_positions = np.full(doc.n_cap, self._pos_sentinel, np.int32)
+            new_positions[:n] = respread
+            new_valid = np.zeros(doc.n_cap, bool)
+            new_valid[:n] = True
+            new_state = eng.full_forward(permuted.tokens,
+                                         _device_copy(new_positions),
+                                         _device_copy(new_valid))
+            self.store.set_hot(doc, new_state)
+            # host mirrors follow the compaction so slot indices keep
+            # matching
+            doc.tokens = doc.tokens[order]
+            doc.valid = new_valid
+            doc.positions = new_positions
+            doc.slots = list(range(n))
+            doc.free = list(range(doc.n_cap - 1, n - 1, -1))
+            doc.touched_from = None
+            self.stats.device_defrags += 1
+            self.stats.full_forwards += 1
+            self._count_shape(("full", doc.n_cap))
 
     # ------------------------------------------------------------ suggestions
 
@@ -1074,44 +1102,47 @@ class BatchServer:
             self._refresh_doc(doc)
 
     def _refresh_doc(self, doc: _BatchDoc) -> None:
-        # Redundant-refresh fast path: the document's content watermarks are
-        # unchanged since the suggestion it already holds (``invalid_from``
-        # clear), so the deterministic greedy continuation cannot differ —
-        # serve the cached tokens without any prefill/dispatch. Reached e.g.
-        # by a re-subscription at an unchanged-or-shorter length.
-        if (doc.invalid_from is None and doc.suggestion is not None
-                and len(doc.suggestion) >= doc.suggest_n):
-            doc.suggestion = doc.suggestion[:doc.suggest_n]
-            doc.suggest_fresh = True
-            self.stats.suggest_cached_hits += 1
-            return
-        sugg = self.suggester
-        eng = self.engine(self.C, self.R)
-        self.store.ensure_hot(doc)  # KV export reads the device state
-        on_token = None
-        if self.on_suggest_token is not None:
-            serial, hook = doc.suggest_serial + 1, self.on_suggest_token
+        with phase(self.stats, "refresh_ns", "serve.batch.refresh",
+                   step=self._step, doc=doc.doc_id):
+            # Redundant-refresh fast path: the document's content watermarks
+            # are unchanged since the suggestion it already holds
+            # (``invalid_from`` clear), so the deterministic greedy
+            # continuation cannot differ — serve the cached tokens without
+            # any prefill/dispatch. Reached e.g. by a re-subscription at an
+            # unchanged-or-shorter length.
+            if (doc.invalid_from is None and doc.suggestion is not None
+                    and len(doc.suggestion) >= doc.suggest_n):
+                doc.suggestion = doc.suggestion[:doc.suggest_n]
+                doc.suggest_fresh = True
+                self.stats.suggest_cached_hits += 1
+                return
+            sugg = self.suggester
+            eng = self.engine(self.C, self.R)
+            self.store.ensure_hot(doc)  # KV export reads the device state
+            on_token = None
+            if self.on_suggest_token is not None:
+                serial, hook = doc.suggest_serial + 1, self.on_suggest_token
 
-            def on_token(tok, _id=doc.doc_id, _serial=serial, _hook=hook):
-                _hook(_id, _serial, int(np.asarray(tok).reshape(-1)[0]))
-        try:
-            toks = sugg.refresh(
-                eng, doc.state, key=doc.doc_id, n_new=doc.suggest_n,
-                invalid_from=doc.invalid_from,
-                export_invalid_from=doc.touched_from, on_token=on_token)
-        except PositionHeadroomError:
-            # the tail gap is exhausted: re-spread the ids (a scheduled
-            # defrag + full-forward re-ingest) and retry once
-            self._defrag(doc)
-            toks = sugg.refresh(
-                eng, doc.state, key=doc.doc_id, n_new=doc.suggest_n,
-                invalid_from=doc.invalid_from,
-                export_invalid_from=doc.touched_from, on_token=on_token)
-        doc.suggestion = toks
-        doc.suggest_fresh = True
-        doc.invalid_from = None
-        doc.suggest_serial += 1
-        self.stats.suggest_refreshes += 1
+                def on_token(tok, _id=doc.doc_id, _serial=serial, _hook=hook):
+                    _hook(_id, _serial, int(np.asarray(tok).reshape(-1)[0]))
+            try:
+                toks = sugg.refresh(
+                    eng, doc.state, key=doc.doc_id, n_new=doc.suggest_n,
+                    invalid_from=doc.invalid_from,
+                    export_invalid_from=doc.touched_from, on_token=on_token)
+            except PositionHeadroomError:
+                # the tail gap is exhausted: re-spread the ids (a scheduled
+                # defrag + full-forward re-ingest) and retry once
+                self._defrag(doc)
+                toks = sugg.refresh(
+                    eng, doc.state, key=doc.doc_id, n_new=doc.suggest_n,
+                    invalid_from=doc.invalid_from,
+                    export_invalid_from=doc.touched_from, on_token=on_token)
+            doc.suggestion = toks
+            doc.suggest_fresh = True
+            doc.invalid_from = None
+            doc.suggest_serial += 1
+            self.stats.suggest_refreshes += 1
 
     # ------------------------------------------------------------- outputs
 

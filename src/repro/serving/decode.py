@@ -35,6 +35,17 @@ def make_serve_step(cfg: ArchConfig, *, sample: bool = False, temperature: float
     return serve_step
 
 
+def make_prefill_step(cfg: ArchConfig):
+    """Returns ``prefill_step(params, caches, tokens, positions) ->
+    (logits, caches)``: ``models.transformer.prefill_step`` over a chunk of
+    ``m`` tokens, under a name of its own for jit and the profiler."""
+
+    def prefill_step(params, caches, tokens, positions):
+        return T.prefill_step(params, cfg, tokens, caches, positions)
+
+    return prefill_step
+
+
 def greedy_continue(step, params, caches, logits_last: jax.Array,
                     gen_positions: jax.Array,
                     on_token=None) -> tuple[jax.Array, jax.Array]:
@@ -85,7 +96,7 @@ def greedy_decode(params, cfg: ArchConfig, prompt: jax.Array, n_new: int,
     if gen_positions is None:
         gen_positions = positions[:, -1:] + 1 + jnp.arange(n_new, dtype=jnp.int32)
     if T.chunkable(cfg):
-        prefill = jax.jit(lambda p, c, t, pos: T.prefill_step(p, cfg, t, c, pos))
+        prefill = jax.jit(make_prefill_step(cfg))
         logits, caches = prefill(params, caches, prompt, positions)
         logits = logits[:, -1:]
     else:

@@ -451,7 +451,7 @@ class FleetRouter:
                            "suggest_refreshes", "suggest_cached_hits",
                            "evictions", "spills", "rehydrations",
                            "hot_hits", "state_touches", "exports",
-                           "imports", "kernel_launches"):
+                           "imports"):
             agg[field_name] = sum(s["batch"][field_name] for s in per_replica)
         for field_name in ("rounds", "deadline_rounds", "full_rounds",
                            "admitted_edits", "admitted_suggests",

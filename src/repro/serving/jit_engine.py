@@ -355,8 +355,9 @@ class JitIncrementalEngine:
         qs, ks, vs, vcs, Ts, cds = [], [], [], [], [], []
         x = x0
         for li in range(self.L):
-            Wl = jax.tree.map(lambda a: a[li], wts["W"])
-            x, (q, k, v, vc, T, codes) = layer(x, Wl)
+            with jax.named_scope(f"layer{li}"):
+                Wl = jax.tree.map(lambda a: a[li], wts["W"])
+                x, (q, k, v, vc, T, codes) = layer(x, Wl)
             xs.append(x)
             qs.append(q); ks.append(k); vs.append(v)
             vcs.append(vc); Ts.append(T); cds.append(codes)
@@ -444,7 +445,8 @@ class JitIncrementalEngine:
         n_real = (state.n_real + is_ins.sum(dtype=jnp.int32)
                   - is_del.sum(dtype=jnp.int32))
 
-        causal, counts = _order_masks(positions, valid)
+        with jax.named_scope("masks"):
+            causal, counts = _order_masks(positions, valid)
 
         # Inserted slots may hold a stale tenant's activations. Zero their
         # k/vc across all layers so the "old contribution" the ΔT patch
@@ -474,153 +476,179 @@ class JitIncrementalEngine:
         overflow = jnp.asarray(False)
 
         for li in range(self.L):
-            Wl = jax.tree.map(lambda a: a[li], wts["W"])
-            x_in = new_x[li]
-            # per-location at dirty rows (garbage lanes are masked out below)
-            h = _ln(x_in[dirty_idx], Wl["ln1_s"], Wl["ln1_b"])
-            q_n = jnp.einsum("cd,dhe->che", h, Wl["wq"]) + Wl["bq"]
-            k_n = jnp.einsum("cd,dhe->che", h, Wl["wk"]) + Wl["bk"]
-            v_n = jnp.einsum("cd,dhe->che", h, Wl["wv"]) + Wl["bv"]
-            vc_n = jnp.einsum("che,hqe->chq", v_n, Wl["cb_per_head"])
+            with jax.named_scope(f"layer{li}"):
+                Wl = jax.tree.map(lambda a: a[li], wts["W"])
+                x_in = new_x[li]
+                with jax.named_scope("qkv"):
+                    # per-location at dirty rows (garbage lanes are masked
+                    # out below)
+                    h = _ln(x_in[dirty_idx], Wl["ln1_s"], Wl["ln1_b"])
+                    q_n = jnp.einsum("cd,dhe->che", h, Wl["wq"]) + Wl["bq"]
+                    k_n = jnp.einsum("cd,dhe->che", h, Wl["wk"]) + Wl["bk"]
+                    v_n = jnp.einsum("cd,dhe->che", h, Wl["wv"]) + Wl["bv"]
+                    vc_n = jnp.einsum("che,hqe->chq", v_n, Wl["cb_per_head"])
 
-            upd = jnp.where(new_mask, dirty_idx, drop)
-            q_all = state.q[li].at[upd].set(q_n, mode="drop")
-            k_all = k_base[li].at[upd].set(k_n, mode="drop")
-            v_all = state.v[li].at[upd].set(v_n, mode="drop")
-            vc_all = vc_base[li].at[upd].set(vc_n, mode="drop")
-            k_old = k_base[li][col_idx]
-            vc_old = vc_base[li][col_idx] * col_old[:, None, None]
-            k_new = k_all[col_idx]
-            vc_new = vc_all[col_idx] * col_new[:, None, None]
+                    upd = jnp.where(new_mask, dirty_idx, drop)
+                    q_all = state.q[li].at[upd].set(q_n, mode="drop")
+                    k_all = k_base[li].at[upd].set(k_n, mode="drop")
+                    v_all = state.v[li].at[upd].set(v_n, mode="drop")
+                    vc_all = vc_base[li].at[upd].set(vc_n, mode="drop")
+                    k_old = k_base[li][col_idx]
+                    vc_old = vc_base[li][col_idx] * col_old[:, None, None]
+                    k_new = k_all[col_idx]
+                    vc_new = vc_all[col_idx] * col_new[:, None, None]
 
-            # column patch over ALL rows: ΔT = new − old contributions.
-            # Column order comes from position ids; rows are masked by the
-            # valid mask so free slots never accumulate patches.
-            col_mask = (
-                (col_old | col_new)[None, :]
-                & (positions[col_idx][None, :] <= positions[:, None])
-            ).astype(jnp.float32)  # [n, Cd]
-            row_valid = valid.astype(jnp.float32)
-            # dirty rows: full row recompute (their causal row of the
-            # position-order mask already reflects inserts/deletes). Hoisted
-            # before the patch so the fused path can pre-scatter it and
-            # exclude those rows from the patch mask — per row the result is
-            # identical to patch-then-overwrite (a dirty row's patch was
-            # discarded by the overwrite; a clean row's patch is unchanged).
-            causal_rows = causal[dirty_idx]  # [Cd, n]
-            w_rows = _gelu(jnp.einsum("che,jhe->hcj", q_all[dirty_idx], k_all)
-                           * m["scale"]) * causal_rows[None]
-            T_rows = jnp.einsum("hcj,jhq->chq", w_rows, vc_all)
-            if self.use_fused_kernel:
-                from repro.kernels.fused_step import fused_patch_assign
+                with jax.named_scope("masks"):
+                    # column patch over ALL rows: ΔT = new − old
+                    # contributions. Column order comes from position ids;
+                    # rows are masked by the valid mask so free slots never
+                    # accumulate patches.
+                    col_mask = (
+                        (col_old | col_new)[None, :]
+                        & (positions[col_idx][None, :] <= positions[:, None])
+                    ).astype(jnp.float32)  # [n, Cd]
+                    row_valid = valid.astype(jnp.float32)
+                    causal_rows = causal[dirty_idx]  # [Cd, n]
 
-                # patch + T accumulate + requantize in ONE launch: the mask
-                # folds every gate (live columns, causal order, row
-                # validity, dirty-row exclusion), so the compiled shape is
-                # blind to which rows/columns are live — the ragged
-                # capacity-class contract (DESIGN.md §9)
-                dirty_dense = jnp.zeros((n,), jnp.float32).at[upd].set(
-                    1.0, mode="drop")
-                pmask = col_mask * (row_valid * (1.0 - dirty_dense))[:, None]
-                T_base = state.T[li].at[upd].set(T_rows, mode="drop")
-                T_all, codes = fused_patch_assign(
-                    state.q[li],
-                    k_new.transpose(1, 0, 2),
-                    k_old.transpose(1, 0, 2),
-                    vc_new.transpose(1, 0, 2),
-                    vc_old.transpose(1, 0, 2),
-                    pmask, T_base, counts, Wl["vq_bias"],
-                    heads_per_vq=m["heads_per_vq"],
-                )
-            else:
-                if self.use_patch_kernel:
-                    from repro.kernels.incr_patch import incr_patch
+                with jax.named_scope("patch"):
+                    # dirty rows: full row recompute (their causal row of
+                    # the position-order mask already reflects
+                    # inserts/deletes). Hoisted before the patch so the
+                    # fused path can pre-scatter it and exclude those rows
+                    # from the patch mask — per row the result is identical
+                    # to patch-then-overwrite (a dirty row's patch was
+                    # discarded by the overwrite; a clean row's patch is
+                    # unchanged).
+                    w_rows = _gelu(
+                        jnp.einsum("che,jhe->hcj", q_all[dirty_idx], k_all)
+                        * m["scale"]) * causal_rows[None]
+                    T_rows = jnp.einsum("hcj,jhq->chq", w_rows, vc_all)
+                    if self.use_fused_kernel:
+                        from repro.kernels.fused_step import (
+                            fused_patch_assign,
+                        )
 
-                    dT = incr_patch(
-                        state.q[li],
-                        k_new.transpose(1, 0, 2),
-                        k_old.transpose(1, 0, 2),
-                        vc_new.transpose(1, 0, 2),
-                        vc_old.transpose(1, 0, 2),
-                        col_mask,
-                        row_valid=row_valid,
-                    )
-                else:
-                    cm = col_mask * row_valid[:, None]
-                    s_new = jnp.einsum("nhe,che->nhc", state.q[li],
-                                       k_new) * m["scale"]
-                    s_old = jnp.einsum("nhe,che->nhc", state.q[li],
-                                       k_old) * m["scale"]
-                    dT = jnp.einsum("nhc,chq->nhq",
-                                    _gelu(s_new) * cm[:, None, :],
-                                    vc_new) - jnp.einsum(
-                        "nhc,chq->nhq", _gelu(s_old) * cm[:, None, :], vc_old)
-                T_all = state.T[li] + dT
-                T_all = T_all.at[upd].set(T_rows, mode="drop")
+                        # patch + T accumulate + requantize in ONE launch:
+                        # the mask folds every gate (live columns, causal
+                        # order, row validity, dirty-row exclusion), so the
+                        # compiled shape is blind to which rows/columns are
+                        # live — the ragged capacity-class contract
+                        # (DESIGN.md §9)
+                        dirty_dense = jnp.zeros((n,), jnp.float32).at[
+                            upd].set(1.0, mode="drop")
+                        pmask = col_mask * (row_valid
+                                            * (1.0 - dirty_dense))[:, None]
+                        T_base = state.T[li].at[upd].set(T_rows, mode="drop")
+                        T_all, codes = fused_patch_assign(
+                            state.q[li],
+                            k_new.transpose(1, 0, 2),
+                            k_old.transpose(1, 0, 2),
+                            vc_new.transpose(1, 0, 2),
+                            vc_old.transpose(1, 0, 2),
+                            pmask, T_base, counts, Wl["vq_bias"],
+                            heads_per_vq=m["heads_per_vq"],
+                        )
+                    else:
+                        if self.use_patch_kernel:
+                            from repro.kernels.incr_patch import incr_patch
 
-                # re-quantize all rows (cheap: O(n·Q)); counts
-                # renormalization after inserts/deletes is automatic —
-                # counts came from the mask
-                s = T_all.reshape(n, m["hq"], m["heads_per_vq"], m["Q"]).sum(2)
-                s = s / counts[:, None, None] + Wl["vq_bias"][None]
-                codes = jnp.argmax(s, axis=-1).astype(jnp.int32)
+                            dT = incr_patch(
+                                state.q[li],
+                                k_new.transpose(1, 0, 2),
+                                k_old.transpose(1, 0, 2),
+                                vc_new.transpose(1, 0, 2),
+                                vc_old.transpose(1, 0, 2),
+                                col_mask,
+                                row_valid=row_valid,
+                            )
+                        else:
+                            cm = col_mask * row_valid[:, None]
+                            s_new = jnp.einsum("nhe,che->nhc", state.q[li],
+                                               k_new) * m["scale"]
+                            s_old = jnp.einsum("nhe,che->nhc", state.q[li],
+                                               k_old) * m["scale"]
+                            dT = jnp.einsum(
+                                "nhc,chq->nhq", _gelu(s_new) * cm[:, None, :],
+                                vc_new) - jnp.einsum(
+                                "nhc,chq->nhq", _gelu(s_old) * cm[:, None, :],
+                                vc_old)
+                        T_all = state.T[li] + dT
+                        T_all = T_all.at[upd].set(T_rows, mode="drop")
 
-            changed = jnp.any(codes != state.codes[li], axis=-1) & valid
-            changed = changed.at[upd].set(True, mode="drop")
-            n_changed = changed.sum()
-            overflow = overflow | (n_changed > R)
+                with jax.named_scope("requant"):
+                    if not self.use_fused_kernel:
+                        # re-quantize all rows (cheap: O(n·Q)); counts
+                        # renormalization after inserts/deletes is
+                        # automatic — counts came from the mask. (The
+                        # fused kernel requantizes inside its patch.)
+                        s = T_all.reshape(n, m["hq"], m["heads_per_vq"],
+                                          m["Q"]).sum(2)
+                        s = s / counts[:, None, None] + Wl["vq_bias"][None]
+                        codes = jnp.argmax(s, axis=-1).astype(jnp.int32)
+                    changed = jnp.any(codes != state.codes[li],
+                                      axis=-1) & valid
+                    changed = changed.at[upd].set(True, mode="drop")
+                    n_changed = changed.sum()
+                    overflow = overflow | (n_changed > R)
 
-            # gather up to R changed rows into the next dirty bucket
-            scores = jnp.where(changed, 1.0, 0.0)
-            _, next_idx = jax.lax.top_k(scores, min(R, n))
-            next_valid = changed[next_idx]
+                with jax.named_scope("propagate"):
+                    # gather up to R changed rows into the next dirty bucket
+                    scores = jnp.where(changed, 1.0, 0.0)
+                    _, next_idx = jax.lax.top_k(scores, min(R, n))
+                    next_valid = changed[next_idx]
 
-            attn = Wl["bo"][None] + sum(
-                Wl["c_wo"][hh][codes[next_idx][:, hh]] for hh in range(m["hq"])
-            )
-            x_mid = x_in[next_idx] + attn
-            h2 = _ln(x_mid, Wl["ln2_s"], Wl["ln2_b"])
-            ffn = _gelu(h2 @ Wl["w_up"] + Wl["b_up"]) @ Wl["w_down"] + Wl["b_down"]
-            x_out_rows = x_mid + ffn
+                with jax.named_scope("mlp"):
+                    attn = Wl["bo"][None] + sum(
+                        Wl["c_wo"][hh][codes[next_idx][:, hh]]
+                        for hh in range(m["hq"]))
+                    x_mid = x_in[next_idx] + attn
+                    h2 = _ln(x_mid, Wl["ln2_s"], Wl["ln2_b"])
+                    ffn = (_gelu(h2 @ Wl["w_up"] + Wl["b_up"]) @ Wl["w_down"]
+                           + Wl["b_down"])
+                    x_out_rows = x_mid + ffn
 
-            keep = next_valid
-            if self.delta_threshold > 0.0:
-                # Sigma-delta gate (DESIGN.md §10): compare each selected
-                # row's fresh recompute against the value it LAST
-                # TRANSMITTED — the stored x[li+1] row — so sub-threshold
-                # drift accumulates across steps and is re-examined on
-                # every later code flip. Suppressed rows still take their
-                # new T/codes at THIS layer (the quantizer state advances;
-                # only the transmission is withheld), write nothing to
-                # x[li+1], and are excluded from the next layer's dirty
-                # bucket and patch columns — i.e. the keep bits fold into
-                # the next layer's engine-built mask. The Python-level
-                # guard keeps the threshold-0 jaxpr untouched.
-                x_prev_rows = state.x[li + 1][next_idx]
-                if self.use_fused_kernel:
-                    from repro.kernels.fused_step import delta_gate
+                    keep = next_valid
+                    if self.delta_threshold > 0.0:
+                        # Sigma-delta gate (DESIGN.md §10): compare each
+                        # selected row's fresh recompute against the value
+                        # it LAST TRANSMITTED — the stored x[li+1] row — so
+                        # sub-threshold drift accumulates across steps and
+                        # is re-examined on every later code flip.
+                        # Suppressed rows still take their new T/codes at
+                        # THIS layer (the quantizer state advances; only the
+                        # transmission is withheld), write nothing to
+                        # x[li+1], and are excluded from the next layer's
+                        # dirty bucket and patch columns — i.e. the keep
+                        # bits fold into the next layer's engine-built mask.
+                        # The Python-level guard keeps the threshold-0 jaxpr
+                        # untouched.
+                        x_prev_rows = state.x[li + 1][next_idx]
+                        if self.use_fused_kernel:
+                            from repro.kernels.fused_step import delta_gate
 
-                    moved = delta_gate(x_out_rows, x_prev_rows,
-                                       self.delta_threshold)
-                else:
-                    moved = (jnp.max(jnp.abs(x_out_rows - x_prev_rows),
-                                     axis=-1) > self.delta_threshold)
-                keep = next_valid & moved
+                            moved = delta_gate(x_out_rows, x_prev_rows,
+                                               self.delta_threshold)
+                        else:
+                            moved = (jnp.max(jnp.abs(x_out_rows
+                                                     - x_prev_rows),
+                                             axis=-1) > self.delta_threshold)
+                        keep = next_valid & moved
 
-            x_next = state.x[li + 1].at[jnp.where(keep, next_idx,
-                                                   drop)].set(
-                x_out_rows, mode="drop")
-            new_x.append(x_next)
-            new_q.append(q_all); new_k.append(k_all); new_v.append(v_all)
-            new_vc.append(vc_all); new_T.append(T_all); new_codes.append(codes)
-            dirty_idx = next_idx
-            new_mask = keep
-            # deeper layers: propagated rows patch old→new; deleted slots
-            # keep riding along as old-only columns
-            col_idx = jnp.concatenate([next_idx, slot_safe])
-            col_old = jnp.concatenate([keep, is_del])
-            col_new = jnp.concatenate([keep,
-                                       jnp.zeros_like(is_del)])
+                    x_next = state.x[li + 1].at[jnp.where(keep, next_idx,
+                                                           drop)].set(
+                        x_out_rows, mode="drop")
+                new_x.append(x_next)
+                new_q.append(q_all); new_k.append(k_all); new_v.append(v_all)
+                new_vc.append(vc_all); new_T.append(T_all)
+                new_codes.append(codes)
+                dirty_idx = next_idx
+                new_mask = keep
+                # deeper layers: propagated rows patch old→new; deleted
+                # slots keep riding along as old-only columns
+                col_idx = jnp.concatenate([next_idx, slot_safe])
+                col_old = jnp.concatenate([keep, is_del])
+                col_new = jnp.concatenate([keep,
+                                           jnp.zeros_like(is_del)])
 
         st = lambda l: jnp.stack(l)
         return JitState(tokens, positions, valid, n_real, st(new_x), st(new_q),

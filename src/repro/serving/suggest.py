@@ -50,8 +50,11 @@ from repro.common.bucketing import next_pow2
 from repro.common.precision import pinned_precision
 from repro.configs.base import ArchConfig
 from repro.models import transformer as T
-from repro.serving.decode import greedy_continue, make_serve_step
+from repro.serving.decode import (
+    greedy_continue, make_prefill_step, make_serve_step,
+)
 from repro.serving.jit_engine import JitIncrementalEngine, JitState
+from repro.serving.trace import phase
 
 
 class PositionHeadroomError(RuntimeError):
@@ -68,6 +71,11 @@ class SuggestStats:
     prefill_rows_recomputed: int = 0  # real rows re-prefilled
     prefill_rows_launched: int = 0  # incl. bucket padding (fixed shapes)
     decode_steps: int = 0
+    # ---- refresh time by phase (ns, ``serving.trace``; span
+    # ``serve.suggest.<phase>``)
+    export_ns: int = 0  # sequence order, reuse boundary, KV export
+    prefill_ns: int = 0  # the re-prefill chunk
+    decode_ns: int = 0  # the greedy continuation
 
     @property
     def prefill_rows_total(self) -> int:
@@ -115,8 +123,7 @@ class SuggestionEngine:
         # k/v rows a refresh reuses
         self._step = jax.jit(pinned_precision(
             make_serve_step(cfg, sample=False)))
-        self._prefill = jax.jit(pinned_precision(
-            lambda p, c, t, pos: T.prefill_step(p, cfg, t, c, pos)))
+        self._prefill = jax.jit(pinned_precision(make_prefill_step(cfg)))
         self._cache: dict = {}
         # residency listener (the state store's budget accounting): called
         # with (key, nbytes) whenever a document's persisted decode cache is
@@ -183,55 +190,60 @@ class SuggestionEngine:
         n_new = self.default_new if n_new is None else int(n_new)
         if n_new < 1:
             raise ValueError("n_new must be >= 1")
-        n_new_cap = next_pow2(n_new)
-        n = int(state.n_real)
-        if n < 1:
-            raise ValueError("cannot suggest over an empty document")
-        n_cap = int(state.tokens.shape[0])
-        # Sequence ordering from the small host-side leaves; the heavy k/v
-        # gather (export_kv) runs only when the decode cache must be rebuilt.
-        # Same sort key as _export_kv_impl (both stable), so the row order
-        # matches the export's on the rebuild path — garbage tail included.
-        host_valid = np.asarray(state.valid)
-        host_positions = np.asarray(state.positions)
-        order = np.argsort(np.where(host_valid, host_positions,
-                                    np.iinfo(np.int32).max), kind="stable")
-        seq_tokens = np.asarray(state.tokens)[order]
-        seq_positions = host_positions[order]
-        last_pos = int(seq_positions[n - 1])
-        if self.pos_headroom(last_pos) < n_new:
-            raise PositionHeadroomError(
-                f"{n_new} continuation ids after position {last_pos} exceed "
-                f"the embedding pool of {self.params['embed']['pos'].shape[0]}"
-                " — defragment the document first")
+        stats = self.stats
+        with phase(stats, "export_ns", "serve.suggest.export"):
+            n_new_cap = next_pow2(n_new)
+            n = int(state.n_real)
+            if n < 1:
+                raise ValueError("cannot suggest over an empty document")
+            n_cap = int(state.tokens.shape[0])
+            # Sequence ordering from the small host-side leaves; the heavy
+            # k/v gather (export_kv) runs only when the decode cache must be
+            # rebuilt. Same sort key as _export_kv_impl (both stable), so the
+            # row order matches the export's on the rebuild path — garbage
+            # tail included.
+            host_valid = np.asarray(state.valid)
+            host_positions = np.asarray(state.positions)
+            order = np.argsort(np.where(host_valid, host_positions,
+                                        np.iinfo(np.int32).max), kind="stable")
+            seq_tokens = np.asarray(state.tokens)[order]
+            seq_positions = host_positions[order]
+            last_pos = int(seq_positions[n - 1])
+            if self.pos_headroom(last_pos) < n_new:
+                pool = self.params["embed"]["pos"].shape[0]
+                raise PositionHeadroomError(
+                    f"{n_new} continuation ids after position {last_pos} "
+                    f"exceed the embedding pool of {pool} — defragment the "
+                    "document first")
 
-        def boundary(watermark: Optional[int]) -> int:
-            # first sequence row whose position id the edits may have
-            # invalidated; the last row is always recomputed so the refresh
-            # yields last-token logits
-            if watermark is None:
-                return n - 1
-            return int(np.searchsorted(seq_positions[:n], watermark, "left"))
+            def boundary(watermark: Optional[int]) -> int:
+                # first sequence row whose position id the edits may have
+                # invalidated; the last row is always recomputed so the
+                # refresh yields last-token logits
+                if watermark is None:
+                    return n - 1
+                return int(np.searchsorted(seq_positions[:n], watermark,
+                                           "left"))
 
-        entry = self._cache.get(key) if key is not None else None
-        if entry is not None and (entry.n_cap != n_cap
-                                  or entry.n_new_cap != n_new_cap):
-            entry = None
-        if entry is not None:
-            p = min(boundary(invalid_from), n - 1)
-            # the reused prefix must be the exact rows the cache encodes
-            if not (np.array_equal(entry.positions[:p], seq_positions[:p])
-                    and np.array_equal(entry.tokens[:p], seq_tokens[:p])):
-                p = 0
-            caches = entry.caches
-        else:
-            p = min(boundary(export_invalid_from), n - 1)
-            exp = engine.export_kv(state)
-            caches = T.caches_from_kv(
-                self.cfg, exp.k[:, None], exp.v[:, None],
-                jnp.zeros((1,), jnp.int32),
-                seq_len=n_cap + n_new_cap, dtype=self.dtype)
-            self.stats.rebuilds += 1
+            entry = self._cache.get(key) if key is not None else None
+            if entry is not None and (entry.n_cap != n_cap
+                                      or entry.n_new_cap != n_new_cap):
+                entry = None
+            if entry is not None:
+                p = min(boundary(invalid_from), n - 1)
+                # the reused prefix must be the exact rows the cache encodes
+                if not (np.array_equal(entry.positions[:p], seq_positions[:p])
+                        and np.array_equal(entry.tokens[:p], seq_tokens[:p])):
+                    p = 0
+                caches = entry.caches
+            else:
+                p = min(boundary(export_invalid_from), n - 1)
+                exp = engine.export_kv(state)
+                caches = T.caches_from_kv(
+                    self.cfg, exp.k[:, None], exp.v[:, None],
+                    jnp.zeros((1,), jnp.int32),
+                    seq_len=n_cap + n_new_cap, dtype=self.dtype)
+                stats.rebuilds += 1
 
         # -------- re-prefill rows [p_eff, n) in one bucketed chunk. The
         # bucket extends the chunk *downward* (recomputing extra reusable
@@ -239,36 +251,39 @@ class SuggestionEngine:
         # full document underfills its bucket, the chunk covers the whole
         # exported buffer — the garbage tail rows land beyond the final
         # length counter, where attention never sees them.
-        M = next_pow2(n - p)
-        p_eff = n - M
-        if p_eff < 0:
-            p_eff, M = 0, n_cap
-        caches = T.set_cache_length(caches, p_eff)
-        chunk_t = jnp.asarray(seq_tokens[p_eff:p_eff + M])[None]
-        chunk_p = jnp.asarray(seq_positions[p_eff:p_eff + M])[None]
-        logits, caches = self._prefill(self.params, caches, chunk_t, chunk_p)
-        caches = T.set_cache_length(caches, n)
-        last_logits = logits[:, n - 1 - p_eff]  # [1, vocab]
+        with phase(stats, "prefill_ns", "serve.suggest.prefill"):
+            M = next_pow2(n - p)
+            p_eff = n - M
+            if p_eff < 0:
+                p_eff, M = 0, n_cap
+            caches = T.set_cache_length(caches, p_eff)
+            chunk_t = jnp.asarray(seq_tokens[p_eff:p_eff + M])[None]
+            chunk_p = jnp.asarray(seq_positions[p_eff:p_eff + M])[None]
+            logits, caches = self._prefill(self.params, caches, chunk_t,
+                                           chunk_p)
+            caches = T.set_cache_length(caches, n)
+            last_logits = logits[:, n - 1 - p_eff]  # [1, vocab]
 
         # -------- greedy continuation on fresh tail position ids
-        gen_pos = jnp.asarray(
-            last_pos + 1 + np.arange(n_new, dtype=np.int32))[None]
-        toks, caches = greedy_continue(self._step, self.params, caches,
-                                       last_logits, gen_pos,
-                                       on_token=on_token)
-        out = np.asarray(toks[0], np.int32)
+        with phase(stats, "decode_ns", "serve.suggest.decode"):
+            gen_pos = jnp.asarray(
+                last_pos + 1 + np.arange(n_new, dtype=np.int32))[None]
+            toks, caches = greedy_continue(self._step, self.params, caches,
+                                           last_logits, gen_pos,
+                                           on_token=on_token)
+            out = np.asarray(toks[0], np.int32)
 
-        if key is not None:
-            self._cache[key] = _SuggestCache(
-                caches=caches, tokens=seq_tokens[:n].copy(),
-                positions=seq_positions[:n].copy(), n=n, n_cap=n_cap,
-                n_new_cap=n_new_cap)
-            self._notify(key, self.cache_nbytes(key))
-        self.stats.refreshes += 1
-        self.stats.prefill_rows_reused += p_eff
-        self.stats.prefill_rows_recomputed += n - p_eff
-        self.stats.prefill_rows_launched += M
-        self.stats.decode_steps += n_new - 1
+            if key is not None:
+                self._cache[key] = _SuggestCache(
+                    caches=caches, tokens=seq_tokens[:n].copy(),
+                    positions=seq_positions[:n].copy(), n=n, n_cap=n_cap,
+                    n_new_cap=n_new_cap)
+                self._notify(key, self.cache_nbytes(key))
+            stats.refreshes += 1
+            stats.prefill_rows_reused += p_eff
+            stats.prefill_rows_recomputed += n - p_eff
+            stats.prefill_rows_launched += M
+            stats.decode_steps += n_new - 1
         return out
 
 

@@ -306,3 +306,29 @@ def test_weights_are_arguments_not_constants(setup):
     for dims in re.findall(r"stablehlo\.constant dense<[^>]*> : "
                            r"tensor<([0-9x]+)x[a-z0-9]+>", text):
         assert np.prod([int(x) for x in dims.split("x")]) < 1024, dims
+
+
+def test_open_documents_keeps_one_ingest_chunk_in_flight(setup, monkeypatch):
+    """A fleet's ingest waits for each chunk's batched full forward before
+    it queues the next, so the device never holds every chunk's batched
+    result and slices at once; the states are the same as ever."""
+    cfg, params, _, _ = setup
+    srv = BatchServer(params, cfg, edit_capacity=4, row_capacity=8,
+                      max_batch=2, min_doc_capacity=16)
+    eng = srv.engine(srv.C, srv.R)
+    outs, ready = [], []
+    real = eng.batch_full_forward
+
+    def spy(*args):
+        ready.append(all(leaf.is_ready() for out in outs
+                         for leaf in jax.tree.leaves(out)))
+        outs.append(real(*args))
+        return outs[-1]
+
+    monkeypatch.setattr(eng, "batch_full_forward", spy)
+    rng = np.random.default_rng(5)
+    docs = {f"d{i}": list(rng.integers(0, cfg.vocab, 20)) for i in range(6)}
+    srv.open_documents(docs)
+    assert len(outs) == 3 and all(ready)
+    for d, toks in docs.items():
+        assert list(srv.tokens(d)) == toks

@@ -306,7 +306,6 @@ def test_server_long_mixed_stream_compiled_shape_budget(setup):
     # 24 edits in dev runs)
     assert srv.stats.traced_shapes <= 12
     assert srv.stats.traced_shapes == srv.stats.rejits  # alias stays true
-    assert srv.stats.kernel_launches_per_edit <= 3.0
     for did, r in ref.items():
         assert list(srv.tokens(did)) == r, did
         doc = srv.docs[did]
