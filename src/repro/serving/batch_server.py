@@ -105,7 +105,7 @@ from repro.serving.jit_engine import (
     JitState, OP_DELETE, OP_INSERT, OP_REPLACE, state_from_host,
     state_nbytes_for, state_to_host,
 )
-from repro.serving.state_store import StateStore
+from repro.serving.state_store import TIER_HOT, StateStore
 from repro.serving.suggest import (
     PositionHeadroomError, SuggestionEngine, SuggestStats,
 )
@@ -150,6 +150,8 @@ class BatchStats:
     device_grows: int = 0  # grows served by the device-side pad_state path
     # (no full-forward re-ingest — existing slots keep their bits)
     rejits: int = 0  # distinct dispatch shapes traced
+    overlapped_dispatches: int = 0  # dispatches launched while an earlier
+    # dispatch of the same step() was still unsynced
     suggest_refreshes: int = 0  # suggestion recomputes served
     suggest_invalidations: int = 0  # fresh suggestions staled by newer edits
     suggest_cached_hits: int = 0  # suggestions served from the cached
@@ -190,7 +192,9 @@ class BatchStats:
     take_ns: int = 0  # snapshots, bucket takes and grouping in step()
     stack_ns: int = 0  # rehydration, stacking and bucket uploads
     launch_ns: int = 0  # the batched edit step's call, until it returns
-    sync_ns: int = 0  # the host waiting for the step's overflow flags
+    sync_ns: int = 0  # the host waiting for a step's overflow flags
+    # (with the next dispatch already launched, the wait for the rest of
+    # the step before it)
     adopt_ns: int = 0  # per-document unstacking and state adoption
     reingest_ns: int = 0  # overflow re-ingests, defrags and grows
     refresh_ns: int = 0  # suggestion refreshes
@@ -224,6 +228,23 @@ class BatchStats:
         low; it is the first-class benchmarked quantity of sharded serving
         (benchmarks/sharded_serving.py)."""
         return self.shard_imbalance_sum / max(self.sharded_dispatches, 1)
+
+
+@dataclass
+class _Launched:
+    """A batched edit dispatch launched on the device and not yet synced:
+    what its adoption needs. The stacked input is not held here (the
+    queued step holds it until it has run)."""
+    docs: list  # the chunk's documents, in member order
+    keep: frozenset  # their ids, which no re-ingest evicts while the
+    # dispatch is in flight
+    rows: list  # padded row -> member index (None = filler row)
+    counts: list  # edits per member
+    loads: list  # dirty slots per mesh shard
+    new_state: object  # the batched result (BatchedJitState)
+    overflow: jax.Array  # per-row overflow flags, on their way to the host
+    shape: tuple  # the traced dispatch shape, for ``rejits``
+    ids: dict  # span ids of the dispatch's phases
 
 
 @dataclass
@@ -810,18 +831,46 @@ class BatchServer:
                     groups.setdefault(
                         (t[0].n_cap, self.C, t[0].row_capacity, t[1]),
                         []).append(t)
+
+            def adopt(launched: _Launched, keep: frozenset = frozenset()):
+                nonlocal applied
+                applied += self._adopt(launched, keep)
+                for d in launched.docs:
+                    undone.pop(id(d))
+
+            # One dispatch in flight ahead: chunk k+1 is launched before
+            # chunk k is synced, so the host's stacking and launch of the
+            # next step run while the device computes the one before. The
+            # chunks of a step hold disjoint documents, and nothing is
+            # carried into the next step() (its take may dispatch the same
+            # documents, whose dispatch needs their adopted states).
+            ahead: Optional[_Launched] = None
             for (n_cap, C, R, kind), members in sorted(groups.items(),
                                                        key=lambda kv: kv[0]):
                 for lo in range(0, len(members), self.max_batch):
                     chunk = members[lo:lo + self.max_batch]
-                    applied += self._dispatch(chunk, n_cap, C, R, kind)
-                    for t in chunk:
-                        undone.pop(id(t[0]), None)
+                    if ahead is not None and any(
+                            self.store.tier(t[0].doc_id) != TIER_HOT
+                            for t in chunk):
+                        # a member's rehydration admits bytes and may
+                        # evict: adopt the dispatch in flight first, so no
+                        # document whose new state is still on its way is
+                        # evicted (a hot chunk admits nothing)
+                        adopt(ahead)
+                        ahead = None
+                    launched = self._launch(chunk, n_cap, C, R, kind,
+                                            overlapped=ahead is not None)
+                    if ahead is not None:
+                        adopt(ahead, launched.keep)
+                    ahead = launched
+            if ahead is not None:
+                adopt(ahead)
         except Exception:
             # a failed take (pool exhausted mid-bucket) or dispatch (device
-            # OOM, interrupt) must not lose edits: every doc not yet served
+            # OOM, interrupt) must not lose edits: every doc not yet adopted
             # rolls back to its pre-take snapshot (host mirrors, slot map,
-            # allocator ids, queue — its device state was never replaced)
+            # allocator ids, queue — its device state was never replaced,
+            # also where its dispatch was launched and not yet synced)
             for d, snap in undone.values():
                 self._restore(d, snap)
             raise
@@ -840,20 +889,25 @@ class BatchServer:
         self._refresh_suggestions()  # no-op when every subscription is fresh
         return total
 
-    def _dispatch(self, chunk: list, n_cap: int, C: int, R: int,
-                  kind: str) -> int:
+    def _launch(self, chunk: list, n_cap: int, C: int, R: int, kind: str,
+                overlapped: bool) -> _Launched:
+        """Stack a chunk's states and launch its batched edit step; the
+        result is adopted later by ``_adopt``. ``overlapped``: an earlier
+        dispatch of the step is still in flight."""
         eng = self.engine(C, R)
         docs = [t[0] for t in chunk]
         buckets = [t[2] for t in chunk]
         counts = [t[3] for t in chunk]
-        ids = dict(step=self._step, dispatch=self.stats.batch_steps + 1,
+        # a dispatch in flight is counted in batch_steps only once adopted
+        ids = dict(step=self._step,
+                   dispatch=self.stats.batch_steps + 1 + overlapped,
                    docs=len(chunk), R=R)
+        keep = frozenset(d.doc_id for d in docs)
         with phase(self.stats, "stack_ns", "serve.batch.stack", **ids):
             # transparent rehydration on touch: every chunk member must be
             # hot for the stacked dispatch — warm/cold members re-upload
             # their snapshots (bit-exact), protected from each other's
             # admissions
-            keep = frozenset(d.doc_id for d in docs)
             for d in docs:
                 self.store.ensure_hot(d, keep=keep)
             # pad to a pow2 batch (multiple of the mesh's batch axis) with
@@ -887,33 +941,53 @@ class BatchServer:
             else:
                 new_state, overflow = eng.batch_apply_deletes(batched, slot)
             del batched
+            # the flags come to the host as soon as the step has run, so
+            # the sync finds them there
+            overflow.copy_to_host_async()
+        if overlapped:
+            self.stats.overlapped_dispatches += 1
+        return _Launched(docs=docs, keep=keep, rows=rows, counts=counts,
+                         loads=loads, new_state=new_state, overflow=overflow,
+                         shape=("edit", B_pad, n_cap, C, R), ids=ids)
+
+    def _adopt(self, launched: _Launched, keep: frozenset) -> int:
+        """Sync a launched dispatch's overflow flags and adopt its states:
+        the batched slice of each member, or a full-forward re-ingest where
+        its flag tripped. ``keep`` names the documents of the dispatch
+        launched after this one, which a re-ingest's admission must not
+        evict. Returns the edits applied."""
+        ids = launched.ids
         with phase(self.stats, "sync_ns", "serve.batch.sync", **ids):
-            overflow = np.asarray(overflow)
+            overflow = np.asarray(launched.overflow)
         with phase(self.stats, "adopt_ns", "serve.batch.adopt", **ids):
             self.stats.batch_steps += 1
-            self.stats.batched_docs += len(chunk)
+            self.stats.batched_docs += len(launched.docs)
             # all three op kinds share one compiled step per (B, n_cap, C,
             # R): the op vector is data, so `kind` is NOT part of the
             # traced shape
-            self._count_shape(("edit", B_pad, n_cap, C, R))
-            self._note_balance(loads)
+            self._count_shape(launched.shape)
+            self._note_balance(launched.loads)
             applied = 0
-            for b, i in enumerate(rows):
+            for b, i in enumerate(launched.rows):
                 if i is None:
                     continue
-                doc = docs[i]
-                applied += counts[i]
-                self.stats.edits_applied += counts[i]
+                doc = launched.docs[i]
+                applied += launched.counts[i]
+                self.stats.edits_applied += launched.counts[i]
                 if overflow[b]:
-                    self._fallback_full_forward(doc)
+                    self._fallback_full_forward(doc, keep)
                 else:
-                    self.store.set_hot(doc, unstack_state(new_state, b))
+                    self.store.set_hot(doc,
+                                       unstack_state(launched.new_state, b))
         return applied
 
     # ------------------------------------------------------------ slow paths
 
-    def _reingest(self, doc: _BatchDoc) -> None:
-        """Rebuild device state from the host mirrors (one full forward)."""
+    def _reingest(self, doc: _BatchDoc,
+                  keep: frozenset = frozenset()) -> None:
+        """Rebuild device state from the host mirrors (one full forward).
+        The admission of the new state evicts no document named in
+        ``keep``."""
         with phase(self.stats, "reingest_ns", "serve.batch.reingest",
                    step=self._step, doc=doc.doc_id):
             eng = self.engine(self.C, self.R)
@@ -924,7 +998,7 @@ class BatchServer:
             resident = (self.store.nbytes(doc.doc_id)
                         if self.store.tier(doc.doc_id) == "hot" else 0)
             self.store.admit(max(new_bytes - resident, 0),
-                             keep=frozenset((doc.doc_id,)))
+                             keep=keep | frozenset((doc.doc_id,)))
             state = eng.full_forward(_device_copy(doc.tokens),
                                      _device_copy(doc.positions),
                                      _device_copy(doc.valid))
@@ -935,11 +1009,11 @@ class BatchServer:
             self.stats.full_forwards += 1
             self._count_shape(("full", doc.n_cap))
 
-    def _fallback_full_forward(self, doc: _BatchDoc) -> None:
+    def _fallback_full_forward(self, doc: _BatchDoc, keep: frozenset) -> None:
         """Overflow: discard the unreliable batched slice, recompute from the
         host mirrors, and double the document's row bucket."""
         self.stats.overflows += 1
-        self._reingest(doc)
+        self._reingest(doc, keep)
         if doc.row_capacity < doc.n_cap:
             doc.row_capacity = min(doc.row_capacity * 2, doc.n_cap)
 

@@ -249,3 +249,137 @@ def test_tight_capacity_property(setup, seed, row_capacity):
     cfg, params = setup
     _run_interleaving(cfg, params, seed=seed, n_docs=2, n_ops=16,
                       row_capacity=row_capacity, max_batch=2)
+
+
+# ------------------------------------------ one dispatch in flight per step
+
+
+def _chunked_server(cfg, params, max_batch):
+    """Four documents in one (n_cap, C, R) group and row capacity 2: the
+    first chunk's wide edits overflow."""
+    srv = BatchServer(params, cfg, edit_capacity=4, row_capacity=2,
+                      max_batch=max_batch, min_doc_capacity=16)
+    rng = np.random.default_rng(11)
+    for i in range(4):
+        srv.open_document(f"d{i}", rng.integers(0, cfg.vocab, 20 + 2 * i))
+    return srv
+
+
+def _edits_for(i):
+    """Documents 0 and 1 edit their first slots (every row after them is
+    dirty), 2 and 3 their last ones."""
+    pos = (0, 1, 2) if i < 2 else (17, 18, 19)
+    return [(p, (7 * i + p) % 32) for p in pos]
+
+
+def _assert_bitwise_equal(a, b, doc_ids):
+    for d in doc_ids:
+        assert list(a.tokens(d)) == list(b.tokens(d)), d
+        for x, y in zip(jax.tree.leaves(a.state(d)),
+                        jax.tree.leaves(b.state(d))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        np.testing.assert_array_equal(a.logits(d), b.logits(d))
+
+
+@pytest.mark.parametrize("max_batch", [1, 2])
+def test_pipelined_step_matches_serial_dispatches(setup, max_batch):
+    """A step of several chunks launches chunk k+1 before it syncs and
+    adopts chunk k, whose overflow re-ingest then runs behind it. Every
+    state and logit equals the same chunks served one step() each, which
+    runs the same programs on the same inputs in the old order."""
+    cfg, params = setup
+    ids = [f"d{i}" for i in range(4)]
+    piped = _chunked_server(cfg, params, max_batch)
+    serial = _chunked_server(cfg, params, max_batch)
+    for i, d in enumerate(ids):
+        for p, t in _edits_for(i):
+            piped.submit_replace(d, p, t)
+    assert piped.step() == 12
+    n_chunks = 4 // max_batch
+    assert piped.stats.batch_steps == n_chunks
+    assert piped.stats.overlapped_dispatches == n_chunks - 1
+    for lo in range(0, 4, max_batch):  # one chunk per step: no overlap
+        for i in range(lo, lo + max_batch):
+            for p, t in _edits_for(i):
+                serial.submit_replace(ids[i], p, t)
+        serial.step()
+    assert serial.stats.overlapped_dispatches == 0
+    assert piped.docs["d0"].row_capacity > 2  # the first chunk overflowed
+    assert piped.stats.overflows == serial.stats.overflows >= 1
+    _assert_bitwise_equal(piped, serial, ids)
+
+
+def test_failed_launch_with_a_dispatch_in_flight_loses_no_edit(
+        setup, monkeypatch):
+    """The launch of chunk 2 raises while chunk 1 is launched and not yet
+    synced: chunk 1 is not adopted, every document rolls back to its
+    snapshot with its device state untouched, and the retry matches a
+    server that never failed."""
+    cfg, params = setup
+    ids = [f"d{i}" for i in range(4)]
+    srv = _chunked_server(cfg, params, 1)
+    clean = _chunked_server(cfg, params, 1)
+    for s in (srv, clean):
+        for i, d in enumerate(ids):
+            for p, t in _edits_for(i):
+                s.submit_replace(d, p, t)
+    before = {d: (srv.docs[d].state, srv.docs[d].seq_tokens().copy(),
+                  list(srv.docs[d].pending)) for d in ids}
+    eng = srv.engine(srv.C, srv.R)
+    real, calls = eng.batch_apply_replaces, []
+
+    def second_fails(*args):
+        calls.append(len(calls))
+        if len(calls) == 2:
+            raise RuntimeError("simulated device failure")
+        return real(*args)
+
+    monkeypatch.setattr(eng, "batch_apply_replaces", second_fails)
+    with pytest.raises(RuntimeError, match="simulated device failure"):
+        srv.step()
+    assert srv.stats.edits_applied == 0 and srv.stats.batch_steps == 0
+    for d in ids:
+        state, toks, pending = before[d]
+        assert srv.docs[d].state is state, d
+        assert list(srv.docs[d].seq_tokens()) == list(toks), d
+        assert list(srv.docs[d].pending) == pending, d
+    monkeypatch.undo()
+    assert srv.flush() == clean.flush() == 12
+    _assert_bitwise_equal(srv, clean, ids)
+
+
+@pytest.mark.parametrize("seed,max_batch", [(0, 1), (1, 2)])
+def test_overlapped_dispatches_count(setup, seed, max_batch):
+    """Every dispatch but the first of its step() is launched with an
+    earlier one in flight, over a mixed stream of several op groups."""
+    cfg, params = setup
+    rng = np.random.default_rng(seed)
+    srv = BatchServer(params, cfg, edit_capacity=4, row_capacity=16,
+                      max_batch=max_batch, min_doc_capacity=16)
+    ref = {f"d{i}": list(rng.integers(0, cfg.vocab, 10 + 4 * i))
+           for i in range(4)}
+    for d, toks in ref.items():
+        srv.open_document(d, toks)
+    steps_with_dispatch = 0
+    for _ in range(6):
+        for d, r in ref.items():
+            kind = rng.choice(["replace", "insert", "delete"])
+            p = int(rng.integers(len(r)))
+            t = int(rng.integers(cfg.vocab))
+            if kind == "insert":
+                srv.submit_insert(d, p, t)
+                r.insert(p, t)
+            elif kind == "delete":
+                srv.submit_delete(d, p)
+                del r[p]
+            else:
+                srv.submit_replace(d, p, t)
+                r[p] = t
+        while srv.pending_count():
+            n = srv.stats.batch_steps
+            srv.step()
+            steps_with_dispatch += srv.stats.batch_steps > n
+    assert srv.stats.overlapped_dispatches == (
+        srv.stats.batch_steps - steps_with_dispatch) > 0
+    for d, r in ref.items():
+        assert list(srv.tokens(d)) == r, d

@@ -243,6 +243,8 @@ def test_budget_evicts_lru_and_pins_hold(setup):
     srv2.submit_replace("d1", 0, 1)
     srv2.flush()
     assert srv2.stats.rehydrations >= 2
+    # the budget cannot hold d1 beside d0 in flight: d0 was adopted first
+    assert srv2.stats.overlapped_dispatches == 0
     _reconcile(srv2)
 
 
