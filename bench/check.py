@@ -5,8 +5,9 @@ tokens, the position ids it was served at, the residual stream of every
 real row before each layer and after the last, the VQ codes each layer
 chose (all of it what the incremental edit step and its ``fused_step``
 kernel left behind), and the last suggestion its stream delivered. The
-program is then freed, and the benchmark's own reference
-(``bench/model.py``) runs over each document. The numbers compared:
+program is then freed, and the reference of the configuration's
+architecture (its module under ``bench/models/``) runs over each
+document. The numbers compared:
 
 * ``token_mismatch``: documents whose served tokens differ from the
   traffic generator's replay of everything sent, or whose device tokens
@@ -46,12 +47,21 @@ that precision.
 from __future__ import annotations
 
 import functools
+import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from model import each_layer, embed, forward, head, token_gaps
+from models.common import token_gaps
+
+
+class _Static(dict):
+    """A configuration's ``model`` group as a static argument of a jitted
+    function."""
+
+    def __hash__(self):
+        return hash(json.dumps(self, sort_keys=True))
 
 
 def _code_gaps(ref_scores, chosen):
@@ -61,44 +71,44 @@ def _code_gaps(ref_scores, chosen):
     return (best - got).max(-1)
 
 
-@functools.partial(jax.jit, static_argnames=("n_heads", "control"))
+@functools.partial(jax.jit, static_argnames=("ref", "model", "control"))
 def _readings(params, toks, pos, valid, xs, codes, sugg, state_rows,
-              sugg_rows, *, n_heads, control):
+              sugg_rows, *, ref, model, control):
     n = state_rows.astype(bool)
-    ref = forward(params, toks, pos, valid, n_heads=n_heads,
-                  precision="highest")
-    scores, outs = each_layer(params, xs, codes, n, n_heads=n_heads)
-    x0 = embed(params, toks, pos)
+    logits = ref.forward(params, model, toks, pos, valid,
+                         precision="highest")
+    scores, outs = ref.each_layer(params, model, xs, codes, n)
+    x0 = ref.embed(params, model, toks, pos)
     if control:
-        low = forward(params, toks, pos, valid, n_heads=n_heads,
-                      precision="high")
+        low = ref.forward(params, model, toks, pos, valid, precision="high")
         served_first = jnp.argmax(low, -1)
         sugg_first = served_first
-        row_err = jnp.max(jnp.abs(low - ref), -1)
-        low_scores, low_outs = each_layer(params, xs, codes, n,
-                                          n_heads=n_heads, precision="high")
+        row_err = jnp.max(jnp.abs(low - logits), -1)
+        low_scores, low_outs = ref.each_layer(params, model, xs, codes, n,
+                                              precision="high")
         chosen = jnp.argmax(low_scores, -1)
         layer_err = jnp.max(jnp.abs(low_outs - outs), -1)
     else:
-        served = head(params, xs[-1])
+        served = ref.head(params, model, xs[-1])
         served_first = jnp.argmax(served, -1)
         sugg_first = sugg
-        row_err = jnp.max(jnp.abs(served - ref), -1)
+        row_err = jnp.max(jnp.abs(served - logits), -1)
         chosen = codes
         layer_err = jnp.concatenate(
             [jnp.max(jnp.abs(xs[:1] - x0), -1),
              jnp.max(jnp.abs(xs[1:] - outs), -1)])
     rows = state_rows[None]
     code_gap = jnp.max(_code_gaps(scores, chosen) * rows)
-    state_gap = jnp.max(token_gaps(ref, served_first) * state_rows)
-    sugg_gap = jnp.max(token_gaps(ref, sugg_first) * sugg_rows)
+    state_gap = jnp.max(token_gaps(logits, served_first) * state_rows)
+    sugg_gap = jnp.max(token_gaps(logits, sugg_first) * sugg_rows)
     return (state_gap, sugg_gap, row_err, code_gap,
             jnp.max(layer_err * rows))
 
 
-def compare(params, model: dict, docs: list, *, max_len: int,
+def compare(module, params, model: dict, docs: list, *, max_len: int,
             control: bool = False) -> dict:
-    """Readings over ``docs``: dicts with ``replay`` (tokens the generator
+    """Readings of ``module``'s reference (the configuration's
+    architecture) over ``docs``: dicts with ``replay`` (tokens the generator
     says the document holds), ``served`` (the server's tokens), ``device``
     (the device state's tokens in position order), ``positions`` (the
     served position ids in sequence order), ``xs`` ([L+1, n, d] residual
@@ -139,8 +149,8 @@ def compare(params, model: dict, docs: list, *, max_len: int,
         sg, gg, rows, cg, le = _readings(
             params, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(valid),
             jnp.asarray(xs), jnp.asarray(codes), jnp.asarray(chosen),
-            jnp.asarray(state_rows), jnp.asarray(sugg_rows),
-            n_heads=model["n_heads"], control=control)
+            jnp.asarray(state_rows), jnp.asarray(sugg_rows), ref=module,
+            model=_Static(model), control=control)
         for k, v in (("state_gap", sg), ("suggest_gap", gg),
                      ("code_gap", cg), ("layer_err", le)):
             worst[k] = max(worst[k], float(v))

@@ -1,6 +1,7 @@
 """Whole edit step's share of the chip's peak: the floor of the useful
-edit operations of the traced window's dispatches (bench/work.py) over the
-traced window times the peak (%)."""
+edit operations of the traced window's dispatches (counted by the
+architecture's module, summed by bench/work.py) over the traced window
+times the peak (%)."""
 
 
 def read(ctx):

@@ -1,7 +1,8 @@
 """Share of its roofline that the fused_step kernel reached in the traced
 window: the least time the chip could take for the floor of the kernel's
-operations and bytes in the window's dispatches (bench/work.py), over the
-kernel's device time (%)."""
+operations and bytes in the window's dispatches (counted by the
+architecture's module, summed by bench/work.py), over the kernel's device
+time (%)."""
 from readers import FUSED_KERNEL, kernel_ns
 
 

@@ -10,33 +10,15 @@ import functools
 import numpy as np
 
 
-def arch_config(model: dict):
-    """The program's ``ArchConfig`` for a configuration file's ``model``."""
-    from repro.configs.base import LayerCfg, uniform_stages
-    from repro.configs.vq_opt_125m import config
-
-    cfg = config(vq_heads=model["vq_heads"])
-    cfg = dataclasses.replace(
-        cfg, name=model["name"], n_layers=model["n_layers"],
-        d_model=model["d_model"], n_heads=model["n_heads"],
-        n_kv_heads=model["n_heads"], d_ff=model["d_ff"],
-        vocab=model["vocab"], max_seq=model["max_seq"],
-        pos_pool=model["pos_pool"],
-        stages=uniform_stages(LayerCfg(mixer="gqa", ffn="gelu"),
-                              model["n_layers"]),
-        vqt=dataclasses.replace(cfg.vqt, n_heads=model["vq_heads"],
-                                codebook_size=model["codebook_size"]))
-    return cfg.validate()
-
-
-def build_server(params, model: dict, serving: dict):
-    """``BatchServer`` under ``AsyncBatchServer``, as the configuration
-    file's ``serving`` group sets them."""
+def build_server(params, arch, serving: dict):
+    """``BatchServer`` under ``AsyncBatchServer`` for the program's
+    ``ArchConfig`` ``arch``, as the configuration file's ``serving`` group
+    sets them."""
     from repro.serving.async_server import AsyncBatchServer
     from repro.serving.batch_server import BatchServer
 
     srv = BatchServer(
-        params, arch_config(model), edit_capacity=serving["edit_capacity"],
+        params, arch, edit_capacity=serving["edit_capacity"],
         row_capacity=serving["row_capacity"], max_batch=serving["max_batch"],
         capacity_class_step=serving["capacity_class_step"],
         delta_threshold=serving["delta_threshold"])

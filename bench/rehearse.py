@@ -57,15 +57,15 @@ def main(argv=None) -> int:
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    import program
     import repro.kernels.fused_step.ops as fused_ops
+    import run
     from repro.serving.batch_engine import BatchedJitEngine
 
     jax.config.update("jax_enable_compilation_cache", False)
     fused_ops.interpret_mode = lambda: False  # compile the real kernel
     with open(os.path.join(BENCH, "configs", args.config + ".json")) as f:
         conf = json.load(f)
-    cfg = program.arch_config(conf["model"])
+    cfg = run.reference(conf["reference"]).arch_config(conf["model"])
     s = conf["serving"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 ``<name>`` is a cell of ``BENCHMARK.json``: one configuration
-(``bench/configs/<config>.json``: model sizes, server settings) under one
+(``bench/configs/<config>.json``: model sizes, server settings, and under
+``reference`` its architecture's module in ``bench/models/``) under one
 traffic mix (``bench/traffic/<traffic>.json``), with the limits of its
 correctness check in ``bench/limits/<name>.json``. Every metric is a reader
 of its own, ``bench/metrics/<metric>.py``. A run is one process: it fails
@@ -14,7 +15,7 @@ measures for ``--seconds``. With ``--trace 0`` the result line holds the
 cell's end-to-end metrics; with ``--trace 1`` its per-layer metrics, read
 from a profiler trace of the window and the server's counters. After the
 window the program is freed and the served output is compared with the
-benchmark's own reference (``bench/check.py``).
+architecture's reference (``bench/check.py``).
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and with ``--trace 1``
@@ -59,24 +60,45 @@ def load_json(*parts):
 
 
 def resolve(workload: str, root: str = ROOT) -> SimpleNamespace:
-    """A cell of ``BENCHMARK.json`` with its configuration, mix, limits and
-    the metrics it reports."""
+    """A cell of ``BENCHMARK.json`` with its configuration, the module of its
+    architecture, its mix, its limits and the metrics it reports."""
     spec = load_json(root, "BENCHMARK.json")
     cells = {w["name"]: w for w in spec["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     conf = {c["name"]: c for c in spec["configs"]}[cell["config"]]
+    config = load_json(root, conf["file"])
 
     def mine(metrics):
         return [m for m in metrics
                 if workload in m.get("workloads", [workload])]
 
     return SimpleNamespace(
-        cell=cell, config=load_json(root, conf["file"]),
+        cell=cell, config=config, root=root,
+        module=reference(config["reference"], root),
         mix=load_json(root, "bench", "traffic", cell["traffic"] + ".json"),
         limits=load_json(root, "bench", "limits", workload + ".json"),
         end_to_end=mine(spec["end_to_end"]), per_layer=mine(spec["per_layer"]))
+
+
+def reference(path: str, root: str = ROOT):
+    """The architecture module at ``path`` (relative to ``root``), imported
+    by its path as ``models.<file name>``: one module object per file."""
+    from models import INTERFACE
+
+    path = os.path.realpath(os.path.join(root, path))
+    name = "models." + os.path.splitext(os.path.basename(path))[0]
+    mod = sys.modules.get(name)
+    if mod is None or os.path.realpath(mod.__file__) != path:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{path} lacks {missing} of models.INTERFACE")
+    return mod
 
 
 def reader(name: str, root: str = ROOT):
@@ -166,7 +188,6 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
     devices = chip_devices(c.cell["chips"]) if require_chip else jax.devices()
     use_cache(cache_dir)
 
-    import model as bench_model
     import program
     import xplane as tr
     import work
@@ -185,10 +206,10 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
         mark = now
 
     m, serving = c.config["model"], c.config["serving"]
-    params = bench_model.make_params(m, seed)
+    params = c.module.make_params(m, seed)
     stage("weights_s")
     plan = Plan(c.mix, m["vocab"], seed, seconds)
-    srv, asrv = program.build_server(params, m, serving)
+    srv, asrv = program.build_server(params, c.module.arch_config(m), serving)
     stage("server_s")
     opens = [asrv.open_document(s.doc_id, s.base) for s in plan.sessions]
     for t in opens:
@@ -251,14 +272,15 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
     del srv, asrv, streams
     gc.collect()
 
-    readings = compare(params, m, served, max_len=c.mix["max_doc_len"])
+    readings = compare(c.module, params, m, served,
+                       max_len=c.mix["max_doc_len"])
     if failed_requests:
         readings["token_mismatch"] += failed_requests
     correct, rows = judge(readings, c.limits)
     out_extra = {}
     if control:
-        ctl = compare(params, m, served, max_len=c.mix["max_doc_len"],
-                      control=True)
+        ctl = compare(c.module, params, m, served,
+                      max_len=c.mix["max_doc_len"], control=True)
         ctl_correct, ctl_rows = judge(ctl, c.limits)
         out_extra["control"] = {"correct": bool(ctl_correct),
                                 "checks": ctl_rows, "readings": ctl}
@@ -279,7 +301,7 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
             with open(dump_trace, "w") as f:
                 json.dump(tr.describe(tr.find_xplane(trace_dir)), f)
         ctx.trace = tr.load(tr.find_xplane(trace_dir))
-        ctx.work = work.dispatch_totals(m, spans.dispatches)
+        ctx.work = work.dispatch_totals(c.module, m, spans.dispatches)
         ctx.peaks = work.peaks(devices[0].device_kind) if require_chip \
             else None
         device["busy_s"] = tr.busy_ns(ctx.trace) / 1e9
@@ -288,7 +310,7 @@ def run_cell(c: SimpleNamespace, seed: int, seconds: float, trace: bool, *,
                                "idle_gaps": tr.idle_gaps(ctx.trace)}
         shutil.rmtree(trace_dir, ignore_errors=True)
     for spec in (c.per_layer if trace else c.end_to_end):
-        value = reader(spec["name"])(ctx)
+        value = reader(spec["name"], c.root)(ctx)
         if value is not None:
             result["metrics"][spec["name"]] = {"value": value,
                                                "unit": spec["unit"]}

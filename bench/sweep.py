@@ -35,13 +35,13 @@ def main(argv=None) -> int:
     run.use_cache(run.CACHE_DIR)
 
     import drive
-    import model as bench_model
     import program
     from traffic import Plan
 
     m = c.config["model"]
-    params = bench_model.make_params(m, args.seed)
-    srv, asrv = program.build_server(params, m, c.config["serving"])
+    params = c.module.make_params(m, args.seed)
+    srv, asrv = program.build_server(params, c.module.arch_config(m),
+                                     c.config["serving"])
     for idx, rate in enumerate(args.rates):
         mix = dict(c.mix, rate_edits_per_s=rate, warmup_s=0.0)
         plan = Plan(mix, m["vocab"], args.seed + idx, args.seconds)

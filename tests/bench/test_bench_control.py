@@ -15,11 +15,13 @@ import pytest
 BENCH = os.path.join(os.path.dirname(__file__), "..", "..", "bench")
 sys.path.insert(0, BENCH)
 
-import model as bm  # noqa: E402
+import run  # noqa: E402
 from check import compare, judge  # noqa: E402
 
 with open(os.path.join(BENCH, "configs", "vq-opt-125m.json")) as f:
-    MODEL = dict(json.load(f)["model"], n_layers=2)
+    CONFIG = json.load(f)
+MODEL = dict(CONFIG["model"], n_layers=2)
+bm = run.reference(CONFIG["reference"])
 MAX_LEN = 512
 
 
@@ -34,10 +36,10 @@ def documents(params, seed):
             np.int32)
         t, p = np.zeros(MAX_LEN, np.int32), np.zeros(MAX_LEN, np.int32)
         t[:n], p[:n] = toks, pos
-        xs, codes = bm.residual_stream(params, jnp.asarray(t), jnp.asarray(p),
-                                       jnp.arange(MAX_LEN) < n,
-                                       n_heads=MODEL["n_heads"])
-        first = int(jnp.argmax(bm.head(params, xs[-1, n - 1:n]), -1)[0])
+        xs, codes = bm.residual_stream(params, MODEL, jnp.asarray(t),
+                                       jnp.asarray(p), jnp.arange(MAX_LEN) < n)
+        first = int(jnp.argmax(bm.head(params, MODEL, xs[-1, n - 1:n]),
+                               -1)[0])
         docs.append({"replay": toks, "served": toks, "device": toks,
                      "positions": pos, "xs": np.asarray(xs)[:, :n],
                      "codes": np.asarray(codes)[:, :n],
@@ -53,9 +55,10 @@ def test_control_is_not_correct(limits_file):
     for seed in (11, 12, 13):
         params = bm.make_params(MODEL, seed)
         docs = documents(params, seed)
-        sound = compare(params, MODEL, docs, max_len=MAX_LEN)
+        sound = compare(bm, params, MODEL, docs, max_len=MAX_LEN)
         correct, rows = judge(sound, limits)
         assert correct, rows
-        control = compare(params, MODEL, docs, max_len=MAX_LEN, control=True)
+        control = compare(bm, params, MODEL, docs, max_len=MAX_LEN,
+                          control=True)
         correct, rows = judge(control, limits)
         assert not correct, rows

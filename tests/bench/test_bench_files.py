@@ -1,14 +1,17 @@
 """BENCHMARK.json is well formed, and every name in it resolves to a file:
-each configuration, traffic mix, limits file and metric reader."""
+each configuration, its architecture's module, traffic mix, limits file and
+metric reader. Outside ``bench/models/`` the harness knows no architecture."""
 import importlib.util
 import json
 import math
 import os
 import re
+import sys
 
 import pytest
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
+sys.path.insert(0, os.path.join(ROOT, "bench"))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
@@ -112,3 +115,44 @@ def test_cells_report_what_the_contract_asks(spec):
         assert m["moves"] in e2e
         assert set(m.get("workloads", [])) <= {w["name"]
                                                for w in spec["workloads"]}
+
+
+@pytest.mark.parametrize("config_file", sorted(os.listdir(
+    os.path.join(ROOT, "bench", "configs"))))
+def test_every_config_names_a_reference(config_file):
+    """Each configuration names its architecture's module, a file under
+    bench/models/ that exports the whole interface."""
+    import run
+    from models import INTERFACE
+
+    conf = _load("bench", "configs", config_file)
+    path = os.path.normpath(conf["reference"])
+    assert path.startswith(os.path.join("bench", "models") + os.sep), path
+    assert os.path.isfile(os.path.join(ROOT, path))
+    mod = run.reference(conf["reference"])
+    assert [f for f in INTERFACE if not callable(getattr(mod, f, None))] == []
+
+
+# keys of the OPT block's model group, and its program config, that only
+# bench/models/vq_opt.py may name
+OPT_ONLY = re.compile(r"""\[\s*["'](n_heads|d_ff|pos_pool|codebook_size"""
+                      r"""|max_seq)["']\s*\]|vq_opt_125m""")
+
+
+def test_harness_names_no_architecture():
+    """Outside bench/models/ no file of the harness reads an OPT-only key or
+    the program's OPT configuration, so another architecture's cells need no
+    edit of it."""
+    found = []
+    bench = os.path.join(ROOT, "bench")
+    for dirpath, dirnames, filenames in os.walk(bench):
+        if dirpath == bench:
+            dirnames.remove("models")
+        for f in filenames:
+            if f.endswith(".py"):
+                path = os.path.join(dirpath, f)
+                with open(path) as fh:
+                    for i, line in enumerate(fh, 1):
+                        if OPT_ONLY.search(line):
+                            found.append(f"{os.path.relpath(path, ROOT)}:{i}")
+    assert found == []

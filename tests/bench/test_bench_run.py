@@ -36,11 +36,9 @@ TYPING_METRICS = [{"name": n, "unit": u} for n, u in (
 def small_cell(loop: str) -> SimpleNamespace:
     """A cell's mix and limits at a size the CPU can serve: the revise cell
     (closed loop), or the typing mix (open loop, subscriptions)."""
-    if loop == "closed":
-        c = run.resolve(WORKLOAD)
-        mix, limits, e2e = c.mix, c.limits, c.end_to_end
-        cell = c.cell
-    else:
+    c = run.resolve(WORKLOAD)
+    mix, limits, e2e, cell = c.mix, c.limits, c.end_to_end, c.cell
+    if loop == "open":
         mix = run.load_json(BENCH, "traffic", "typing_suggest.json")
         limits = run.load_json(BENCH, "limits", "opt125m.typing_suggest.json")
         e2e, cell = TYPING_METRICS, {"name": "typing", "chips": 1}
@@ -51,8 +49,8 @@ def small_cell(loop: str) -> SimpleNamespace:
                    subscribe_tokens=4)
     return SimpleNamespace(cell=cell, config={"model": MODEL,
                                               "serving": SERVING},
-                           mix=mix, limits=limits, end_to_end=e2e,
-                           per_layer=[])
+                           root=ROOT, module=c.module, mix=mix,
+                           limits=limits, end_to_end=e2e, per_layer=[])
 
 
 @pytest.fixture(scope="module")
